@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 from typing import List, Tuple
 
@@ -48,19 +47,9 @@ def _block(tree):
 def engine_vs_interp(iters: int = 3, quick: bool = False,
                      ) -> List[Tuple[str, float, str]]:
     # Persist this section's XLA executables across bench runs (the VM
-    # datapath compiles once per machine); restored afterwards so other
-    # benchmark sections keep whatever cache config the process had.
-    prev_cache = None
-    if os.environ.get("REPRO_MVE_XLA_CACHE", None) != "":
-        try:
-            prev_cache = vm.enable_disk_cache()
-        except Exception:
-            pass
-    try:
-        return _engine_vs_interp(iters=iters, quick=quick)
-    finally:
-        if prev_cache is not None:
-            vm.restore_disk_cache(prev_cache)
+    # datapath compiles once per machine).
+    vm.enable_disk_cache()
+    return _engine_vs_interp(iters=iters, quick=quick)
 
 
 def _engine_vs_interp(iters: int, quick: bool,

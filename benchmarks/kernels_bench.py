@@ -1,6 +1,7 @@
-"""Wall-clock microbenchmarks of the Pallas kernels (interpret mode on
-CPU) against the pure-jnp oracles — validates dispatch overhead and gives
-a per-op cost sheet for the serving path."""
+"""Host wall-clock microbenchmarks of the Pallas kernels (compiled on
+TPU, interpreted elsewhere) against the pure-jnp oracles — validates
+dispatch overhead and gives a per-op cost sheet for the serving path.
+Rows do not name a device; on a CPU they time the Pallas interpreter."""
 from __future__ import annotations
 
 import time
@@ -13,7 +14,6 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.bitplane_gemm import bitplane_matmul, int8_matmul
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.mdgather import mdgather
 
 RNG = np.random.default_rng(0)
 
@@ -30,15 +30,6 @@ def _time(fn, *args, iters: int = 5) -> float:
 
 def kernel_microbench() -> List[Tuple[str, float, str]]:
     rows = []
-
-    # mdgather: 8192-lane 3D strided gather vs XLA gather
-    src = jnp.asarray(RNG.standard_normal(1 << 15).astype(np.float32))
-    dims, strides = (128, 8, 8), (1, 0, 1024)
-    t_pl = _time(lambda s: mdgather(s, dims, strides, 0), src)
-    t_ref = _time(lambda s: ref.mdgather_ref(s, dims, strides, 0), src)
-    rows.append(("kernels/mdgather_pallas", t_pl, "interpret"))
-    rows.append(("kernels/mdgather_ref", t_ref,
-                 f"ratio={t_pl/t_ref:.1f}x"))
 
     # int8 GEMM 256x256x256
     x = jnp.asarray(RNG.integers(-128, 128, (256, 256)).astype(np.int8))

@@ -5,7 +5,7 @@ kernel frontend (docs/FRONTEND.md) — through the MVE execution engine
 (docs/ENGINE.md; the default program-as-data VM shares one XLA executable
 across the whole sweep, validating numerics per pattern), prices it on
 the bit-serial engine vs the 1-D RVV lowering, and shows the same
-multi-dim access executed by the Pallas TPU kernels (gather + scatter =
+multi-dim access through the jnp vsld/vsst oracles (gather + scatter =
 the transpose pattern).
 
     PYTHONPATH=src python examples/mve_patterns.py
@@ -15,8 +15,7 @@ import numpy as np
 
 from repro.core import MVEConfig, cache_info, cost, rvv
 from repro.core.patterns import PATTERNS, run_pattern
-from repro.kernels.mdgather import mdgather
-from repro.kernels.mdscatter import mdscatter
+from repro.kernels.ref import mdgather_ref, mdscatter_ref
 
 
 def main():
@@ -39,14 +38,14 @@ def main():
           f"{info.vm_signatures} VM signature(s) / "
           f"{info.vm_xla_compiles} XLA compilation(s)")
 
-    print("\nPallas kernels: matrix transpose via mdgather + mdscatter")
+    print("\nvsld/vsst oracles: matrix transpose via gather + scatter")
     m = jnp.arange(64.0, dtype=jnp.float32)
-    cols = mdgather(m, dims=(8, 8), strides=(8, 1), base=0)
-    out = mdscatter(jnp.zeros_like(m), cols, dims=(8, 8),
-                    strides=(1, 8), base=0)
+    cols = mdgather_ref(m, dims=(8, 8), strides=(8, 1), base=0)
+    out = mdscatter_ref(jnp.zeros_like(m), cols, dims=(8, 8),
+                        strides=(1, 8), base=0)
     np.testing.assert_allclose(np.asarray(out).reshape(8, 8),
                                np.asarray(m).reshape(8, 8).T)
-    print("  8x8 transpose through the TMU-analogue kernels: OK")
+    print("  8x8 transpose through the multi-dim strided access: OK")
 
 
 if __name__ == "__main__":

@@ -41,10 +41,10 @@ Bit-exactness discipline (the stepwise interpreter stays the oracle):
   costs ~1 ms per 8K lanes; a skipped cond costs ~30 us).
 
 The one datapath compile a process ever pays can also be cached across
-processes via JAX's persistent compilation cache (:func:`enable_disk_cache`
-— opt-in; ``benchmarks/engine_bench.py`` enables it for its section, or
-set ``REPRO_MVE_XLA_CACHE=<dir>``), and :func:`prewarm` can overlap it
-with program lowering on a background thread.
+processes via JAX's persistent compilation cache (:func:`enable_disk_cache`,
+called by the entry points that want it: ``chip_smoke.py`` and
+``benchmarks/engine_bench.py``), and :func:`prewarm` can overlap it with
+program lowering on a background thread.
 
 Design note with the full tensor encoding: docs/ENGINE.md ("VM lowering").
 """
@@ -136,40 +136,29 @@ def fire_fault_hook(site: str, **ctx) -> None:
         hook(site, **ctx)
 
 
-def enable_disk_cache(path: Optional[str] = None):
-    """Opt into JAX's persistent compilation cache: the VM's "compile the
-    machine once" then holds per *machine*, not per process.
+#: The compile cache's directory when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed path inside the checkout (listed in ``.gitignore``).
+#: The path is part of what the cache finds again, so it must not move.
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    Opt-in, not default: jax 0.4.x's cache serialization aborts on some
-    executables outside the VM's (observed with the training-step jits of
-    this repo on XLA:CPU), so the process-global cache is only switched on
-    for workloads that want it — ``benchmarks/engine_bench.py`` does, and
-    setting ``REPRO_MVE_XLA_CACHE=<dir>`` enables it at import.  Returns
-    the previous (cache_dir, min_compile_secs) pair for
-    :func:`restore_disk_cache`; both config updates happen only after the
-    cache directory exists, so a failure leaves the config untouched.
+
+def enable_disk_cache() -> str:
+    """Turn on JAX's persistent compilation cache, so the VM's "compile
+    the machine once" holds per machine, not per process.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    used as it is; otherwise the cache lives at :data:`CACHE_DIR`.
+    Returns the directory in use.
     """
-    prev = (jax.config.jax_compilation_cache_dir,
-            jax.config.jax_persistent_cache_min_compile_time_secs)
-    path = path or os.environ.get("REPRO_MVE_XLA_CACHE") or \
-        os.path.expanduser("~/.cache/repro_mve_xla")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    return prev
-
-
-def restore_disk_cache(prev) -> None:
-    """Undo :func:`enable_disk_cache` with its returned value."""
-    jax.config.update("jax_compilation_cache_dir", prev[0])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", prev[1])
-
-
-if os.environ.get("REPRO_MVE_XLA_CACHE"):      # explicit opt-in only
-    try:
-        enable_disk_cache()
-    except Exception:                          # pragma: no cover - best effort
-        pass
+    return path
 
 
 # ---------------------------------------------------------------------------
@@ -179,11 +168,12 @@ if os.environ.get("REPRO_MVE_XLA_CACHE"):      # explicit opt-in only
 class AotJit:
     """``jax.jit`` plus explicit AOT warmup and a compile counter.
 
-    ``jit_fn.lower(...).compile()`` does *not* populate the jit's internal
-    dispatch cache in jax 0.4.x — calling the wrapped function afterwards
-    would silently re-trace.  This wrapper keeps the AOT executable and
-    routes calls with matching (shape, dtype) signatures to it, so
-    :meth:`warmup` genuinely removes the first-call compile cliff.
+    The executable that ``jit_fn.lower(...).compile()`` returns is not
+    one the jit's own call path dispatches to, so calling the wrapped
+    function afterwards would trace again.  This wrapper keeps the AOT
+    executable and routes calls with matching (shape, dtype) signatures
+    to it, so :meth:`warmup` genuinely removes the first-call compile
+    cliff.
     ``compiles`` counts distinct XLA compilations this wrapper triggered.
     """
 

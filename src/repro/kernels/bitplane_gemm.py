@@ -26,36 +26,37 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+
+from .platform import pallas_call
 
 BM, BN = 128, 128
 
 
+def _dot_i8(x, w):
+    """int8 x int8 -> int32 on the MXU (int32 operands are refused)."""
+    return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
 def _int8_kernel(x_ref, w_ref, o_ref):
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
-    o_ref[...] = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    o_ref[...] = _dot_i8(x_ref[...], w_ref[...])
 
 
 def _bitplane_kernel(nbits: int, x_ref, w_ref, o_ref):
-    x = x_ref[...].astype(jnp.int32)
-    wu = w_ref[...].astype(jnp.int32) & 0xFF
-    acc = jnp.zeros((x.shape[0], wu.shape[1]), jnp.int32)
+    x = x_ref[...]
+    wi = w_ref[...].astype(jnp.int32)
+    acc = jnp.zeros((x.shape[0], wi.shape[1]), jnp.int32)
     for b in range(nbits):                      # bit-serial over planes
-        plane = (wu >> b) & 1
-        partial = jax.lax.dot_general(
-            x, plane, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        plane = ((wi >> b) & 1).astype(jnp.int8)
+        partial = _dot_i8(x, plane)
         # two's complement: the top plane is the negative power
         sign = -1 if b == nbits - 1 else 1
         acc = acc + sign * (partial << b)
     o_ref[...] = acc
 
 
-def _tiled_call(kernel, x, w, interpret):
+def _tiled_call(kernel, x, w):
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -64,29 +65,26 @@ def _tiled_call(kernel, x, w, interpret):
         x = jnp.pad(x, ((0, pm), (0, 0)))
         w = jnp.pad(w, ((0, 0), (0, pn)))
     gm, gn = x.shape[0] // BM, w.shape[1] // BN
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(gm, gn),
         in_specs=[pl.BlockSpec((BM, k), lambda i, j: (i, 0)),
                   pl.BlockSpec((k, BN), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((BM, BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], w.shape[1]), jnp.int32),
-        interpret=interpret,
     )(x, w)
     return out[:m, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_matmul(x: jnp.ndarray, w: jnp.ndarray,
-                interpret: bool = True) -> jnp.ndarray:
+@jax.jit
+def int8_matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """Exact int8 x int8 -> int32 matmul, (M,K) @ (K,N)."""
-    return _tiled_call(_int8_kernel, x, w, interpret)
+    return _tiled_call(_int8_kernel, x, w)
 
 
-@functools.partial(jax.jit, static_argnames=("nbits", "interpret"))
-def bitplane_matmul(x: jnp.ndarray, w: jnp.ndarray, nbits: int = 8,
-                    interpret: bool = True) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("nbits",))
+def bitplane_matmul(x: jnp.ndarray, w: jnp.ndarray,
+                    nbits: int = 8) -> jnp.ndarray:
     """Bit-serial-structured int matmul; identical to int8_matmul for
     nbits=8, proportionally cheaper for narrower weights."""
-    return _tiled_call(functools.partial(_bitplane_kernel, nbits),
-                       x, w, interpret)
+    return _tiled_call(functools.partial(_bitplane_kernel, nbits), x, w)

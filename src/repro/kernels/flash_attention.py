@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -75,11 +77,11 @@ def _flash_kernel(scale: float, causal: bool, bq: int, bk: int,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k", "interpret"))
+                                             "block_k"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, scale: float | None = None,
-                    block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
-                    interpret: bool = True) -> jnp.ndarray:
+                    block_q: int = DEFAULT_BQ,
+                    block_k: int = DEFAULT_BK) -> jnp.ndarray:
     """(B, H, Sq, D) attention over (B, H, Sk, D) keys/values.
 
     Sq/Sk are padded to block multiples internally; the causal diagonal is
@@ -103,7 +105,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     kernel = functools.partial(_flash_kernel, scale, causal, bq, bk,
                                skp, sk, sk - sq)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(b * h, sqp // bq),
         in_specs=[
@@ -113,6 +115,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sqp, d), q.dtype),
-        interpret=interpret,
     )(qf, kf, vf)
     return out[:, :sq, :].reshape(b, h, sq, d)

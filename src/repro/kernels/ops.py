@@ -1,46 +1,27 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Every op dispatches between the Pallas kernel (TPU target; ``interpret``
-mode on CPU) and the pure-jnp oracle in :mod:`repro.kernels.ref`.  The
-models call through here so a single flag flips the whole framework
-between kernel and XLA paths.
+Every op dispatches between the Pallas kernel (compiled on TPU,
+interpreted elsewhere — :mod:`repro.kernels.platform`) and the pure-jnp
+oracle in :mod:`repro.kernels.ref`.  The models call through here so a
+single flag flips the whole framework between kernel and XLA paths.
 """
 from __future__ import annotations
 
-import functools
 import os
-from typing import Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import ref
 from .bitplane_gemm import bitplane_matmul, int8_matmul
 from .flash_attention import flash_attention as _flash_pallas
-from .mdgather import mdgather as _mdgather_pallas
 
 # Models use the oracle path by default on CPU (fast XLA fusion); tests and
 # TPU deployments flip this on.
 _USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-# Sources above this size do not fit a VMEM-resident gather tile.
-_VMEM_GATHER_LIMIT = 2 ** 20
 
 
 def use_pallas() -> bool:
     return _USE_PALLAS
-
-
-def mdv_gather(src: jnp.ndarray, dims: Sequence[int],
-               strides: Sequence[int], base: int = 0,
-               force_pallas: bool | None = None) -> jnp.ndarray:
-    """MVE vsld: multi-dimensional strided gather from a flat buffer."""
-    dims = tuple(int(d) for d in dims)
-    strides = tuple(int(s) for s in strides)
-    pallas = _USE_PALLAS if force_pallas is None else force_pallas
-    if pallas and src.size <= _VMEM_GATHER_LIMIT:
-        return _mdgather_pallas(src, dims, strides, base)
-    return ref.mdgather_ref(src, dims, strides, base)
 
 
 def quantized_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,

@@ -42,7 +42,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         g = hq // kh
         kt = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1)
         vt = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1)
-        out = kops.flash_attention(qt, kt, vt, causal=causal)
+        out = kops.flash_attention(qt, kt, vt, causal=causal,
+                                   force_pallas=True)
         return out.transpose(0, 2, 1, 3)
 
     # GQA: repeat kv heads so the merged head axis shards like Megatron

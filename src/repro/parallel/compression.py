@@ -17,23 +17,14 @@ Two entry points:
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# replication-checking kwarg was renamed check_rep -> check_vma in jax
-_NO_CHECK = {k: False for k in ("check_vma", "check_rep")
-             if k in inspect.signature(shard_map).parameters}
 
 
 def quantize_int8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -101,7 +92,7 @@ def make_dp_train_step(loss_fn: Callable, optimizer_update: Callable,
             in_specs=(rep, rep, rep,
                       jax.tree.map(lambda _: sharded, batch)),
             out_specs=(rep, rep, rep, rep),
-            **_NO_CHECK,
+            check_vma=False,
         )(params, opt_state, residual, batch)
 
     return jax.jit(step)

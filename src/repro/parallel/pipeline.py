@@ -20,21 +20,11 @@ from __future__ import annotations
 
 from typing import Callable
 
-import inspect
-
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# replication-checking kwarg was renamed check_rep -> check_vma in jax
-_NO_CHECK = {k: False for k in ("check_vma", "check_rep")
-             if k in inspect.signature(shard_map).parameters}
 
 
 def pipeline_apply(stage_fn: Callable, mesh: Mesh, num_stages: int,
@@ -80,7 +70,7 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, num_stages: int,
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **_NO_CHECK,
+        check_vma=False,
     )
 
 

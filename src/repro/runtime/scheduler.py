@@ -138,9 +138,8 @@ class ServeResult:
     :class:`repro.core.engine.ExecutionResult` for the common fields.
 
     ``tier`` records which executor produced it: ``"vm"`` / ``"fused"``
-    (batched dispatches), ``"single"`` (un-batched engine dispatch) or
-    ``"oracle"`` (stepwise-interpreter fallback of the degradation
-    ladder).
+    (``batch_size`` 1 for an un-batched dispatch) or ``"oracle"``
+    (stepwise-interpreter fallback of the degradation ladder).
 
     ``trace`` is materialized lazily for batched results (a fresh copy of
     the compile-time static trace): serving loops that never read it pay
@@ -159,7 +158,7 @@ class ServeResult:
         self.regs = regs
         self.tag = tag
         self.batch_size = batch_size   # how many requests shared the dispatch
-        self.tier = tier               # "vm" | "fused" | "single" | "oracle"
+        self.tier = tier               # "vm" | "fused" | "oracle"
         self._trace = trace
         self._trace_fn = trace_fn
         self.kernel = kernel           # frontend Kernel, when submitted as one
@@ -953,7 +952,7 @@ class MVEScheduler:
                     self._note_open()
                 mem, regs, tag = ref
         return ServeResult(memory=mem, regs=regs, tag=tag, batch_size=1,
-                           tier="single", trace=state.trace,
+                           tier=runner.mode, trace=state.trace,
                            kernel=t.kernel)
 
     def _oracle(self, cfg: MVEConfig):
@@ -1034,10 +1033,10 @@ class MVEScheduler:
                         mem, regs, tag = ref
                 return [ServeResult(memory=mem,
                                     regs=regs, tag=tag,
-                                    batch_size=1, tier="single",
+                                    batch_size=1, tier=runner.mode,
                                     trace=state.trace,
                                     kernel=tickets[0].kernel)]
-            return tickets, "single", fin_single
+            return tickets, runner.mode, fin_single
 
         runner = fused if fused is not None else cp
         tier = "vm" if fused is None and routed_vm else "fused"

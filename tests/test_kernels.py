@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas kernels vs their ref oracles
+(interpreted on CPU, compiled on TPU — repro.kernels.platform), and the
+MVE gather/scatter oracles vs Algorithm 1 written as literal loops."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,15 +10,38 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ref
 from repro.kernels.bitplane_gemm import bitplane_matmul, int8_matmul
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.mdgather import mdgather
-from repro.kernels.ops import mdv_gather, quantized_matmul
+from repro.kernels.ops import quantized_matmul
 
 RNG = np.random.default_rng(0)
 
 
 # ---------------------------------------------------------------------------
-# mdgather
+# mdgather (vsld semantics)
 # ---------------------------------------------------------------------------
+
+def _loop_addresses(dims, strides, base):
+    """Algorithm 1 as literal nested loops, x (dims[0]) fastest."""
+    addrs = []
+    for lane in range(int(np.prod(dims))):
+        rem, addr = lane, base
+        for length, stride in zip(dims, strides):
+            addr += (rem % length) * stride
+            rem //= length
+        addrs.append(addr)
+    return np.asarray(addrs, dtype=np.int64)
+
+
+def _loop_gather(src, dims, strides, base):
+    return np.asarray(src)[_loop_addresses(dims, strides, base)]
+
+
+def _loop_scatter(dst, vals, dims, strides, base):
+    """Lanes stored in order, so the highest lane wins a collision."""
+    out = np.array(dst)
+    for lane, addr in enumerate(_loop_addresses(dims, strides, base)):
+        out[addr] = np.asarray(vals)[lane]
+    return out
+
 
 @st.composite
 def gather_case(draw):
@@ -34,28 +59,27 @@ def test_mdgather_matches_ref(case):
     dims, strides, base = case
     span = base + sum((l - 1) * s for l, s in zip(dims, strides)) + 1
     src = jnp.asarray(RNG.standard_normal(span + 8).astype(np.float32))
-    got = mdgather(src, dims, strides, base)
-    want = ref.mdgather_ref(src, dims, strides, base)
-    np.testing.assert_allclose(got, want)
+    got = ref.mdgather_ref(src, dims, strides, base)
+    np.testing.assert_array_equal(got, _loop_gather(src, dims, strides, base))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32, jnp.bfloat16])
 def test_mdgather_dtypes(dtype):
     src = jnp.arange(4096).astype(dtype)
     dims, strides = (4, 8, 16), (1, 0, 5)
-    got = mdgather(src, dims, strides, 3)
-    want = ref.mdgather_ref(src, dims, strides, 3)
+    got = ref.mdgather_ref(src, dims, strides, 3)
+    want = _loop_gather(src, dims, strides, 3)
+    assert got.dtype == src.dtype
     np.testing.assert_array_equal(np.asarray(got, np.float64),
                                   np.asarray(want, np.float64))
 
 
 def test_mdgather_large_lane_count():
-    """Exercises multiple (8,128) grid tiles."""
+    """The paper's full 8192-lane geometry."""
     src = jnp.asarray(RNG.standard_normal(1 << 15).astype(np.float32))
     dims, strides = (128, 64), (1, 128)          # 8192 lanes
-    got = mdv_gather(src, dims, strides, 0, force_pallas=True)
-    want = ref.mdgather_ref(src, dims, strides, 0)
-    np.testing.assert_allclose(got, want)
+    got = ref.mdgather_ref(src, dims, strides, 0)
+    np.testing.assert_array_equal(got, _loop_gather(src, dims, strides, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -140,11 +164,8 @@ def test_flash_matches_chunked_model_path():
 
 
 # ---------------------------------------------------------------------------
-# mdscatter
+# mdscatter (vsst semantics)
 # ---------------------------------------------------------------------------
-
-from repro.kernels.mdscatter import mdscatter
-
 
 @settings(max_examples=15, deadline=None)
 @given(gather_case())
@@ -154,18 +175,18 @@ def test_mdscatter_matches_ref(case):
     total = int(np.prod(dims))
     dst = jnp.asarray(RNG.standard_normal(span + 8).astype(np.float32))
     vals = jnp.asarray(RNG.standard_normal(total).astype(np.float32))
-    got = mdscatter(dst, vals, dims, strides, base)
-    want = ref.mdscatter_ref(dst, vals, dims, strides, base)
-    np.testing.assert_allclose(got, want)
+    got = ref.mdscatter_ref(dst, vals, dims, strides, base)
+    np.testing.assert_array_equal(
+        got, _loop_scatter(dst, vals, dims, strides, base))
 
 
 def test_mdscatter_collision_last_lane_wins():
     """Stride-0 output dims collide; the highest lane's value lands."""
     dst = jnp.zeros(8, jnp.float32)
     vals = jnp.asarray([1.0, 2.0, 3.0, 4.0, 5.0, 6.0])
-    got = mdscatter(dst, vals, dims=(3, 2), strides=(1, 0), base=2)
-    want = ref.mdscatter_ref(dst, vals, (3, 2), (1, 0), 2)
-    np.testing.assert_array_equal(got, want)
+    got = ref.mdscatter_ref(dst, vals, (3, 2), (1, 0), 2)
+    np.testing.assert_array_equal(
+        got, _loop_scatter(dst, vals, (3, 2), (1, 0), 2))
     np.testing.assert_array_equal(np.asarray(got[2:5]), [4.0, 5.0, 6.0])
 
 
@@ -175,10 +196,10 @@ def test_mdscatter_roundtrip_with_gather():
     Section IV pattern)."""
     src = jnp.asarray(RNG.standard_normal(64).astype(np.float32))
     dims = (8, 8)
-    vals = mdgather(src, dims, (8, 1), 0)     # read columns
-    same = mdscatter(jnp.zeros_like(src), vals, dims, (8, 1), 0)
+    vals = ref.mdgather_ref(src, dims, (8, 1), 0)     # read columns
+    same = ref.mdscatter_ref(jnp.zeros_like(src), vals, dims, (8, 1), 0)
     np.testing.assert_allclose(same, src)
-    trans = mdscatter(jnp.zeros_like(src), vals, dims, (1, 8), 0)
+    trans = ref.mdscatter_ref(jnp.zeros_like(src), vals, dims, (1, 8), 0)
     np.testing.assert_allclose(
         np.asarray(trans).reshape(8, 8),
         np.asarray(src).reshape(8, 8).T)

@@ -211,7 +211,7 @@ def test_kernels_package_lazy_import():
         "import sys; import repro.kernels as kp; from repro.kernels "
         "import ref; assert 'repro.kernels.ref' in sys.modules; "
         "assert 'repro.kernels.ops' not in sys.modules; "
-        "assert 'repro.kernels.mdgather' not in sys.modules; "
+        "assert 'repro.kernels.flash_attention' not in sys.modules; "
         "assert hasattr(ref, 'tree_sum_ref'); "
         "assert 'ops' in dir(kp)"
     )

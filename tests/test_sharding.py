@@ -100,19 +100,12 @@ def test_compressed_allreduce_subprocess():
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.parallel.compression import compressed_allreduce_mean
-        import inspect
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
-        # replication checking kwarg was renamed check_rep -> check_vma
-        sig = inspect.signature(shard_map).parameters
-        kw = {k: False for k in ("check_vma", "check_rep") if k in sig}
         mesh = make_mesh({"data": 4})
         x = jnp.asarray(np.random.default_rng(0)
                         .standard_normal((4, 64)).astype(np.float32))
-        f = shard_map(lambda v: compressed_allreduce_mean(v[0], "data"),
-                      mesh=mesh, in_specs=P("data"), out_specs=P(), **kw)
+        f = jax.shard_map(
+            lambda v: compressed_allreduce_mean(v[0], "data"),
+            mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)
         got = f(x)
         want = x.mean(axis=0)
         err = float(jnp.abs(got - want).max())
