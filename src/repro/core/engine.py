@@ -73,6 +73,7 @@ from .cost import TraceEvent
 from .machine import (JNP_DTYPE, ControlState, MVEConfig, apply_config,
                       cbs_from_lane_mask, flatten_indices, lane_dim_mask,
                       store_layout, stream_shape, touched_lines)
+from .tracing import LOWER, span, to_device, to_host
 from .vm import AotJit, VMProgram, VMUnsupported, fire_fault_hook
 from .vm import cache_info as _vm_cache_info
 from .vm import set_fault_hook  # noqa: F401  (re-export: one hook registry)
@@ -410,10 +411,10 @@ class CompiledProgram:
 
     def _donatable(self, memory) -> jnp.ndarray:
         """The executables donate (write through) their memory operand, so
-        it must be a jax-owned buffer: copy=True protects caller-owned
+        it must be a jax-owned buffer: a fresh copy protects caller-owned
         device arrays and prevents zero-copy aliasing of caller numpy
         buffers (same-dtype CPU device_put does not copy)."""
-        return jnp.array(memory, copy=True)
+        return to_device(memory)
 
     @staticmethod
     def _vm_memory_dtype(memory) -> bool:
@@ -474,6 +475,17 @@ class CompiledProgram:
             raise
         except Exception as e:
             raise DispatchError(f"dispatch failed ({self.mode} mode): "
+                                f"{type(e).__name__}: {e}") from e
+
+    @staticmethod
+    def wait(pending) -> None:
+        """Block until a :meth:`run_async` or :meth:`run_batch_async`
+        dispatch's device outputs are ready, copying nothing (the wait
+        that finalizing would otherwise hide in its first copy)."""
+        try:
+            jax.block_until_ready(pending[1])
+        except Exception as e:
+            raise FinalizeError(f"waiting for a dispatch failed: "
                                 f"{type(e).__name__}: {e}") from e
 
     def finalize_run(self, pending) -> Tuple[jnp.ndarray, ExecutionResult]:
@@ -538,6 +550,9 @@ class CompiledProgram:
                                 f"mode): {type(e).__name__}: {e}") from e
 
     def finalize_batch(self, pending):
+        """``(memories, regs, tag)`` of a :meth:`run_batch_async`
+        dispatch: host arrays from the VM, device arrays from the fused
+        function."""
         fire_fault_hook("engine.finalize", tier=self.mode)
         try:
             kind, out = pending
@@ -603,8 +618,7 @@ class CompiledProgram:
         for step in self.steps:
             ev = step.event
             if step.rand_slot is not None:
-                addr = np.asarray(rand_addrs[step.rand_slot],
-                                  dtype=np.int64)
+                addr = to_host(rand_addrs[step.rand_slot]).astype(np.int64)
                 ev = dataclasses.replace(ev, lines=touched_lines(
                     addr, step.lane_mask, step.instr.dtype.nbytes))
             else:
@@ -775,7 +789,8 @@ def compile_program(program: isa.Program,
     # first insertion wins below and the loser is dropped.
     fire_fault_hook("engine.compile", tier=mode)
     try:
-        built = CompiledProgram(program, cfg, mode=mode)
+        with span(LOWER):
+            built = CompiledProgram(program, cfg, mode=mode)
     except _PASSTHROUGH:
         raise
     except Exception as e:

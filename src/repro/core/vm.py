@@ -64,6 +64,7 @@ from jax import lax
 from . import isa
 from .isa import DType, Op
 from .machine import MVEConfig, OOB_BASE, next_pow2
+from .tracing import TABLES, span, to_device, to_host
 
 # -- signature buckets ------------------------------------------------------
 N_REGS = 8           # dense register-file slots (virtual regs are remapped)
@@ -183,14 +184,12 @@ class AotJit:
         self._seen = set()
         self._lock = threading.Lock()
         self.compiles = 0
-        self.calls = 0
 
     @staticmethod
     def _key(args):
         return tuple((tuple(a.shape), str(a.dtype)) for a in args)
 
     def __call__(self, *args):
-        self.calls += 1
         key = self._key(args)
         compiled = self._aot.get(key)
         if compiled is not None:
@@ -606,9 +605,10 @@ class VMProgram:
         self.cfg = cfg
         self.n_random = n_random
         lanes = cfg.lanes
-        self._lower(steps, lanes)
-        self.slots_bucket = next_pow2(max(self.n_steps, MIN_SLOTS))
-        self._pad_tables(lanes)
+        with span(TABLES):
+            self._lower(steps, lanes)
+            self.slots_bucket = next_pow2(max(self.n_steps, MIN_SLOTS))
+            self._pad_tables(lanes)
 
     # -- lowering ----------------------------------------------------------
     def _lower(self, steps, lanes: int) -> None:
@@ -862,10 +862,10 @@ class VMProgram:
         mem_size = np.asarray(memory).shape[0]
         sig = self._signature(mem_size)
         ex = _executor(sig)
-        # copy=True: the executable donates (and therefore writes through)
-        # this buffer — it must be jax-owned, not a zero-copy alias of the
+        # A fresh buffer: the executable donates (and therefore writes
+        # through) it — it must be jax-owned, not a zero-copy alias of the
         # short-lived numpy padding buffer.
-        buf = jnp.array(self._pad_memory(memory, sig[3]), copy=True)
+        buf = to_device(self._pad_memory(memory, sig[3]))
         out = ex.single(buf, *self._args(mem_size), *self.tables)
         return (mem_size, out)
 
@@ -879,7 +879,7 @@ class VMProgram:
         """
         fire_fault_hook("vm.finalize", tier="vm")
         mem_size, (mem, regfile, tag, addrs) = pending
-        return (np.array(np.asarray(mem)[:mem_size]), self._regs(regfile),
+        return (np.array(to_host(mem)[:mem_size]), self._regs(regfile),
                 tag, self._rand_addrs(addrs))
 
     def run(self, memory):
@@ -895,14 +895,14 @@ class VMProgram:
         mem_size = mems.shape[-1]
         sig = self._signature(mem_size)
         ex = _executor(sig)
-        buf = jnp.array(self._pad_memory(mems, sig[3]), copy=True)
+        buf = to_device(self._pad_memory(mems, sig[3]))
         out = ex.batch(buf, *self._args(mem_size), *self.tables)
         return (mem_size, out)
 
     def finalize_batch(self, pending):
         fire_fault_hook("vm.finalize", tier="vm")
         mem_size, (mem, regfile, tag, _) = pending
-        return (np.array(np.asarray(mem)[..., :mem_size]),
+        return (np.array(to_host(mem)[..., :mem_size]),
                 self._regs(regfile, batched=True), tag)
 
     def run_batch(self, memories):
@@ -925,7 +925,7 @@ class VMProgram:
         """Typed register values, reconstructed host-side in numpy (no
         per-program XLA dispatches; values are bit-identical to the eager
         executors' typed arrays)."""
-        rf = np.array(regfile)           # owned copy, not a device view
+        rf = np.array(to_host(regfile))   # owned copy
         regs = {}
         for vreg, s in self.regmap.items():
             dt = self.final_dtype.get(vreg)
@@ -944,7 +944,7 @@ class VMProgram:
     def _rand_addrs(self, addrs_out):
         if not self.n_random:
             return []
-        addrs = np.asarray(addrs_out)
+        addrs = to_host(addrs_out)
         return [addrs[self.rand_slot_to_step[r]].astype(np.int64)
                 for r in range(self.n_random)]
 

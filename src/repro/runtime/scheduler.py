@@ -113,6 +113,8 @@ from ..core import isa
 from ..core.cost import TraceEvent
 from ..core.engine import CompiledProgram, cache_info, compile_program
 from ..core.machine import MVEConfig, next_pow2
+from ..core.tracing import (DISPATCH, FETCH, GROUP, IDLE, PROMOTE, RESOLVE,
+                            SYNC, WINDOW, counting, span, to_host)
 from ..resilience.audit import ResultAuditor
 from ..resilience.breaker import CircuitBreaker, RetryPolicy
 from ..resilience.errors import (CancelledError, DeadlineExceededError,
@@ -208,6 +210,8 @@ class Ticket:
         self.deadline = deadline       # absolute perf_counter() deadline
         self.submitted_at = submitted_at if submitted_at is not None \
             else time.perf_counter()
+        self.queued_at: Optional[float] = None   # entered the pending queue
+        self.taken_at: Optional[float] = None    # a serve cycle took it
         self.done_at: Optional[float] = None
         self._event = threading.Event()
         self._resolve_lock = threading.Lock()
@@ -294,6 +298,11 @@ class SchedulerStats:
     audit_corrected: int = 0     # audited results replaced by the reference
     worker_restarts: int = 0     # background worker deaths survived
     worker_errors: int = 0       # serve-cycle failures caught by the backstop
+    # -- serve path (docs/SERVING.md "Observability") ----------------------
+    taken: int = 0               # requests a serve cycle took from the queue
+    queue_wait_s: float = 0.0    # their summed time in the queue
+    h2d_bytes: int = 0           # bytes copied host -> device by dispatches
+    d2h_bytes: int = 0           # bytes copied device -> host by finalizes
 
     @property
     def batch_efficiency(self) -> float:
@@ -509,6 +518,7 @@ class MVEScheduler:
             self._seen.move_to_end(pk)
             while len(self._seen) > _SEEN_CAP:
                 self._seen.popitem(last=False)
+            t.queued_at = time.perf_counter()
             self._pending.append(t)
             self._wake.notify()
         return t
@@ -522,8 +532,7 @@ class MVEScheduler:
         when all are resolved — the deterministic mode tests replay."""
         while True:
             with self._lock:
-                batch, self._pending = self._pending, []
-                self._space.notify_all()
+                batch = self._take()
             if not batch:
                 return
             self._serve(batch)
@@ -631,20 +640,25 @@ class MVEScheduler:
             batch: List[Ticket] = []
             try:
                 with self._lock:
-                    while not self._pending and not self._closed:
-                        self._wake.wait()
+                    if not self._pending and not self._closed:
+                        with span(IDLE):
+                            while not self._pending and not self._closed:
+                                self._wake.wait()
                     if self._closed and not self._pending:
                         return
                     deadline = time.perf_counter() + self.max_wait_ms / 1e3
                     # batching window: wait for more work until the window
                     # closes or a full batch is ready
-                    while (len(self._pending) < self.max_batch
-                           and not self._closed):
-                        left = deadline - time.perf_counter()
-                        if left <= 0 or not self._wake.wait(timeout=left):
-                            break
-                    batch, self._pending = self._pending, []
-                    self._space.notify_all()
+                    if len(self._pending) < self.max_batch \
+                            and not self._closed:
+                        with span(WINDOW):
+                            while (len(self._pending) < self.max_batch
+                                   and not self._closed):
+                                left = deadline - time.perf_counter()
+                                if left <= 0 or \
+                                        not self._wake.wait(timeout=left):
+                                    break
+                    batch = self._take()
                 self._heartbeat.beat(_WORKER_HOST)
                 if self._injector is not None:
                     self._injector.worker_tick()   # may kill this worker
@@ -672,11 +686,26 @@ class MVEScheduler:
                 for t in batch:
                     t._resolve(error=e)
 
+    def _take(self) -> List[Ticket]:
+        """Pop every pending ticket (caller holds ``_lock``), stamping
+        ``taken_at`` and counting each one's time in the queue."""
+        batch, self._pending = self._pending, []
+        self._space.notify_all()
+        now = time.perf_counter()
+        for t in batch:
+            t.taken_at = now
+            self.stats.queue_wait_s += now - t.queued_at
+        self.stats.taken += len(batch)
+        return batch
+
     def _requeue(self, batch: List[Ticket]) -> None:
         alive = [t for t in batch if not t.done()]
         if not alive:
             return
         with self._lock:
+            now = time.perf_counter()
+            for t in alive:
+                t.queued_at = now
             self._pending[:0] = alive       # head: preserve arrival order
             self._wake.notify_all()
 
@@ -690,7 +719,7 @@ class MVEScheduler:
         bookkeeping (each still serves only tickets it popped itself).
         The ``finally`` backstop upholds the resolution guarantee even
         against internal scheduler bugs."""
-        with self._serve_lock:
+        with self._serve_lock, counting(self.stats):
             try:
                 self._serve_locked(batch)
             except (KeyboardInterrupt, SystemExit):
@@ -707,34 +736,8 @@ class MVEScheduler:
                         self.stats.worker_errors += 1
 
     def _serve_locked(self, batch: List[Ticket]) -> None:
-        self.stats.drains += 1
-        now = time.perf_counter()
-        live: List[Ticket] = []
-        for t in batch:
-            if t.done():                    # cancelled / shed / pre-resolved
-                continue
-            if t.deadline is not None and now > t.deadline:
-                self.stats.deadline_misses += 1
-                t._resolve(error=DeadlineExceededError(
-                    f"request {t.rid} missed its deadline before "
-                    f"dispatch"))
-                continue
-            live.append(t)
-
-        buckets: "OrderedDict[tuple, OrderedDict[tuple, List[Ticket]]]" = \
-            OrderedDict()
-        for t in live:
-            # Per-target signature bucketing: the leading tag keeps one
-            # target's groups from stacking with another's even when the
-            # VM signature coincides (their cost models differ; pricing
-            # rides on the ticket's target).
-            key = (t.target,) + tuple(t.cp.batch_group_key(t.memory))
-            gkey = (t.program, key)
-            buckets.setdefault(key, OrderedDict()).setdefault(
-                gkey, []).append(t)
-            if len(self._group_keys_seen) < _BUCKET_STAT_CAP:
-                self._group_keys_seen.add(key)
-        self.stats.signature_buckets = len(self._group_keys_seen)
+        with span(GROUP):
+            buckets = self._group(batch)
 
         inflight = []   # (ctx, tickets, tier, finalize_thunk)
         for key, groups in buckets.items():
@@ -746,20 +749,14 @@ class MVEScheduler:
             # full fused cap.
             routed_vm = key[1] == "vm"
             for (prog, _), tickets in groups.items():
-                tickets = [t for t in tickets if not t.done()]
+                with span(GROUP):
+                    tickets = self._admit(tickets)
+                    fused, due = self._promotion(key, tickets[0]) \
+                        if tickets else (None, False)
                 if not tickets:
                     continue
-                pk = (tickets[0].program, tickets[0].cfg,
-                      tickets[0].target)
-                if self._quarantine_active(pk):
-                    self.stats.quarantine_rejects += len(tickets)
-                    for t in tickets:
-                        t._resolve(error=QuarantinedError(
-                            f"request {t.rid}: program is quarantined "
-                            f"after repeated failures (cooldown "
-                            f"{self.quarantine_cooldown_s:.0f}s)"))
-                    continue
-                fused = self._promotable_safe(key, tickets[0])
+                if due:
+                    fused = self._promote(key, tickets[0])
                 ctx = _DispatchCtx(prog=prog, key=key, fused=fused,
                                    routed_vm=routed_vm)
                 btier = "fused" if fused is not None else tickets[0].cp.mode
@@ -798,22 +795,95 @@ class MVEScheduler:
                 self._recover_group(
                     ctx, [t for t in tickets if not t.done()], e)
                 continue
-            self._stragglers.record(btier, time.perf_counter() - t0)
-            self._breaker.record_success((ctx.key, btier))
-            for t, r in zip(tickets, results):
-                t._resolve(result=r)
+            with span(RESOLVE):
+                self._stragglers.record(btier, time.perf_counter() - t0)
+                self._breaker.record_success((ctx.key, btier))
+                for t, r in zip(tickets, results):
+                    t._resolve(result=r)
 
-    def _promotable_safe(self, key, ticket) -> Optional[CompiledProgram]:
-        """:meth:`_promotable` behind the fused-tier breaker: a failed
-        promotion compile is a tier failure, not a request failure — the
-        group still serves on its base tier."""
+    def _group(self, batch: List[Ticket]):
+        """Drop what is resolved or past its deadline, and bucket the
+        rest: signature bucket -> (program, bucket) -> tickets."""
+        self.stats.drains += 1
+        now = time.perf_counter()
+        live: List[Ticket] = []
+        for t in batch:
+            if t.done():                    # cancelled / shed / pre-resolved
+                continue
+            if t.deadline is not None and now > t.deadline:
+                self.stats.deadline_misses += 1
+                t._resolve(error=DeadlineExceededError(
+                    f"request {t.rid} missed its deadline before "
+                    f"dispatch"))
+                continue
+            live.append(t)
+
+        buckets: "OrderedDict[tuple, OrderedDict[tuple, List[Ticket]]]" = \
+            OrderedDict()
+        for t in live:
+            # Per-target signature bucketing: the leading tag keeps one
+            # target's groups from stacking with another's even when the
+            # VM signature coincides (their cost models differ; pricing
+            # rides on the ticket's target).
+            key = (t.target,) + tuple(t.cp.batch_group_key(t.memory))
+            gkey = (t.program, key)
+            buckets.setdefault(key, OrderedDict()).setdefault(
+                gkey, []).append(t)
+            if len(self._group_keys_seen) < _BUCKET_STAT_CAP:
+                self._group_keys_seen.add(key)
+        self.stats.signature_buckets = len(self._group_keys_seen)
+        return buckets
+
+    def _admit(self, tickets: List[Ticket]) -> List[Ticket]:
+        """A group's tickets still to serve: unresolved ones, unless their
+        program is quarantined (then each resolves with
+        ``QuarantinedError``)."""
+        tickets = [t for t in tickets if not t.done()]
+        if not tickets:
+            return tickets
+        pk = (tickets[0].program, tickets[0].cfg, tickets[0].target)
+        if not self._quarantine_active(pk):
+            return tickets
+        self.stats.quarantine_rejects += len(tickets)
+        for t in tickets:
+            t._resolve(error=QuarantinedError(
+                f"request {t.rid}: program is quarantined "
+                f"after repeated failures (cooldown "
+                f"{self.quarantine_cooldown_s:.0f}s)"))
+        return []
+
+    def _promotion(self, key, ticket
+                   ) -> Tuple[Optional[CompiledProgram], bool]:
+        """The lookups of promotion: ``(fused, due)``, the fused-tier
+        executable of a hot program (``None`` while it stays in its base
+        tier, when promotion is off, the program already runs fused, or the
+        fused tier's breaker is open) and whether its promotion compile is
+        due (:meth:`_promote`).  Promotion heat and the fused compilation
+        are both per ``(program, config, target)`` — one target's
+        promotion never serves (or evicts) another's."""
         if self.promote_after is None or ticket.cp.mode == "fused":
-            return None
+            return None, False
         if not self._breaker.allow((key, "fused")):
             self.stats.breaker_skips += 1
-            return None
+            return None, False
+        pk = (ticket.program, ticket.cfg, ticket.target)
+        hot = self._promoted.get(pk)
+        if hot is not None:
+            self._promoted.move_to_end(pk)
+            return hot, False
+        return None, self._seen.get(pk, 0) >= self.promote_after
+
+    def _promote(self, key, ticket) -> Optional[CompiledProgram]:
+        """Compile a hot program into the fused tier.  A failed promotion
+        compile is a tier failure, not a request failure: the group still
+        serves on its base tier."""
+        pk = (ticket.program, ticket.cfg, ticket.target)
         try:
-            return self._promotable(ticket)
+            with span(PROMOTE):
+                if self._injector is not None:
+                    self._injector.compile([ticket.rid], tier="fused")
+                hot = compile_program(list(pk[0]), ticket.cfg, mode="fused",
+                                      cache_tag=ticket.target)
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception:
@@ -821,6 +891,11 @@ class MVEScheduler:
             self._breaker.record_failure((key, "fused")) and \
                 self._note_open()
             return None
+        self._promoted[pk] = hot
+        while len(self._promoted) > _PROMOTED_CAP:
+            self._promoted.popitem(last=False)
+        self.stats.promotions += 1
+        return hot
 
     def _note_open(self) -> bool:
         self.stats.breaker_opens += 1
@@ -940,7 +1015,7 @@ class MVEScheduler:
                 mem_j, state = runner.run(t.memory)
         else:
             mem_j, state = runner.run(t.memory)
-        mem = np.asarray(mem_j)
+        mem = to_host(mem_j)
         regs, tag = state.regs, state.tag
         if self._auditor is not None and self._auditor.should_audit(t.rid):
             self.stats.audit_checked += 1
@@ -996,129 +1071,108 @@ class MVEScheduler:
     def _dispatch(self, prog: tuple, tickets: List[Ticket], fused,
                   routed_vm: bool = True, shielded: bool = False):
         """Launch one group asynchronously; returns a finalize thunk."""
+        n = len(tickets)
         cp = tickets[0].cp
+        runner = fused if fused is not None else cp
         btier = "fused" if fused is not None else cp.mode
+        if n == 1:
+            tier, rows = runner.mode, 1
+        else:
+            tier = "vm" if fused is None and routed_vm else "fused"
+            # Pad the stack to a power of two so each program compiles at
+            # most log2(max_batch) batched executables; padded rows replay
+            # the first request's image and are dropped after the dispatch.
+            rows = next_pow2(n)
         inj = self._injector
         rids = [t.rid for t in tickets]
-        if inj is not None:
-            inj.dispatch(rids, btier, shielded=shielded)
-        n = len(tickets)
         auditor = self._auditor
-        if n == 1:
-            # Singleton: skip the vmap wrapper (and get the exact
-            # random-access trace for free via finalize_run).
-            runner = fused if fused is not None else cp
-            pending = runner.run_async(tickets[0].memory)
-            self.stats.dispatches += 1
-            self.stats.singles += 1
-            self.stats.max_batch_seen = max(self.stats.max_batch_seen, 1)
+        stats = self.stats
+        with span(DISPATCH, tier=tier, n=n, rows=rows):
+            if inj is not None:
+                inj.dispatch(rids, btier, shielded=shielded)
+            if n == 1:
+                # Singleton: skip the vmap wrapper (and get the exact
+                # random-access trace for free via finalize_run).
+                pending = runner.run_async(tickets[0].memory)
+                stats.singles += 1
+            else:
+                mems = [np.asarray(t.memory) for t in tickets]
+                stacked = np.stack(mems + [mems[0]] * (rows - n))
+                pending = runner.run_batch_async(stacked)
+                stats.batched_requests += n
+                if tier == "fused":
+                    stats.fused_batches += 1
+                else:
+                    stats.vm_batches += 1
+            stats.dispatches += 1
+            stats.max_batch_seen = max(stats.max_batch_seen, n)
 
-            def fin_single():
+        def fin_single():
+            with span(SYNC):
+                runner.wait(pending)
+            with span(FETCH):
                 mem, state = runner.finalize_run(pending)
-                mem = np.asarray(mem)
+                mem = to_host(mem)
+            with span(RESOLVE):
+                t = tickets[0]
                 regs, tag = state.regs, state.tag
                 if inj is not None and not shielded:
                     mem = inj.finalize(rids, btier, mem)
-                if auditor is not None \
-                        and auditor.should_audit(tickets[0].rid):
-                    self.stats.audit_checked += 1
-                    ref = auditor.check(tickets[0].program,
-                                        tickets[0].memory, tickets[0].cfg,
-                                        mem, tag, runner.mode)
-                    if ref is not None:
-                        self.stats.audit_corrected += 1
-                        self._breaker.record_failure(
-                            (ticket_key(tickets[0]), btier)) and \
-                            self._note_open()
-                        mem, regs, tag = ref
-                return [ServeResult(memory=mem,
-                                    regs=regs, tag=tag,
-                                    batch_size=1, tier=runner.mode,
-                                    trace=state.trace,
-                                    kernel=tickets[0].kernel)]
-            return tickets, runner.mode, fin_single
-
-        runner = fused if fused is not None else cp
-        tier = "vm" if fused is None and routed_vm else "fused"
-        # Pad the stack to a power of two so each program compiles at most
-        # log2(max_batch) batched executables; padded rows replay the
-        # first request's image and are dropped after the dispatch.
-        bucket = next_pow2(n)
-        mems = [np.asarray(t.memory) for t in tickets]
-        stacked = np.stack(mems + [mems[0]] * (bucket - n))
-        pending = runner.run_batch_async(stacked)
-        self.stats.dispatches += 1
-        self.stats.batched_requests += n
-        self.stats.max_batch_seen = max(self.stats.max_batch_seen, n)
-        if tier == "fused":
-            self.stats.fused_batches += 1
-        else:
-            self.stats.vm_batches += 1
-
-        def fin_batch():
-            mem, regs, tag = runner.finalize_batch(pending)
-            # One device->host transfer per array (not per request): the
-            # per-request views below slice host memory.
-            mem = np.asarray(mem)
-            tag = np.asarray(tag)
-            regs = {r: np.asarray(v) for r, v in regs.items()}
-            if inj is not None and not shielded:
-                rows = {t.rid: b for b, t in enumerate(tickets)}
-                mem = inj.finalize(rids, btier, mem, rows)
-
-            def trace_fn():
-                # Deferred static_trace access too: unread traces cost
-                # nothing on the dispatch hot path.
-                return [dataclasses.replace(ev) for ev in cp.static_trace]
-
-            out = []
-            for b in range(n):
-                t = tickets[b]
-                rmem = mem[b]
-                rregs = {r: v[b] for r, v in regs.items()}
-                rtag = tag[b]
                 if auditor is not None and auditor.should_audit(t.rid):
-                    self.stats.audit_checked += 1
-                    ref = auditor.check(t.program, t.memory, t.cfg,
-                                        rmem, rtag, runner.mode)
+                    stats.audit_checked += 1
+                    ref = auditor.check(t.program, t.memory, t.cfg, mem,
+                                        tag, runner.mode)
                     if ref is not None:
-                        self.stats.audit_corrected += 1
+                        stats.audit_corrected += 1
                         self._breaker.record_failure(
                             (ticket_key(t), btier)) and self._note_open()
-                        rmem, rregs, rtag = ref
-                out.append(ServeResult(
-                    memory=rmem, regs=rregs, tag=rtag,
-                    batch_size=n, tier=tier,
-                    trace_fn=trace_fn, kernel=t.kernel))
-            return out
-        return tickets, tier, fin_batch
+                        mem, regs, tag = ref
+                return [ServeResult(memory=mem, regs=regs, tag=tag,
+                                    batch_size=1, tier=runner.mode,
+                                    trace=state.trace, kernel=t.kernel)]
 
-    def _promotable(self, ticket: Ticket) -> Optional[CompiledProgram]:
-        """The fused-tier executable for a hot program, compiling it on
-        first promotion; ``None`` while the program stays in the VM tier
-        (or when promotion is off / the program already runs fused).
-        Promotion heat and the fused compilation are both per
-        ``(program, config, target)`` — one target's promotion never
-        serves (or evicts) another's."""
-        cp = ticket.cp
-        if self.promote_after is None or cp.mode == "fused":
-            return None
-        pk = (ticket.program, ticket.cfg, ticket.target)
-        hot = self._promoted.get(pk)
-        if hot is not None:
-            self._promoted.move_to_end(pk)
-            return hot
-        if self._seen.get(pk, 0) < self.promote_after:
-            return None
-        if self._injector is not None:
-            self._injector.compile([ticket.rid], tier="fused")
-        hot = compile_program(list(pk[0]), ticket.cfg, mode="fused",
-                              cache_tag=ticket.target)
-        self._promoted[pk] = hot
-        while len(self._promoted) > _PROMOTED_CAP:
-            self._promoted.popitem(last=False)
-        self.stats.promotions += 1
-        return hot
+        def fin_batch():
+            with span(SYNC):
+                runner.wait(pending)
+            with span(FETCH):
+                mem, regs, tag = runner.finalize_batch(pending)
+                # One device->host transfer per array (not per request):
+                # the per-request views below slice host memory.
+                mem = to_host(mem)
+                tag = to_host(tag)
+                regs = {r: to_host(v) for r, v in regs.items()}
+            with span(RESOLVE):
+                if inj is not None and not shielded:
+                    rows_of = {t.rid: b for b, t in enumerate(tickets)}
+                    mem = inj.finalize(rids, btier, mem, rows_of)
+
+                def trace_fn():
+                    # Deferred static_trace access too: unread traces
+                    # cost nothing on the dispatch hot path.
+                    return [dataclasses.replace(ev)
+                            for ev in cp.static_trace]
+
+                out = []
+                for b in range(n):
+                    t = tickets[b]
+                    rmem = mem[b]
+                    rregs = {r: v[b] for r, v in regs.items()}
+                    rtag = tag[b]
+                    if auditor is not None and auditor.should_audit(t.rid):
+                        stats.audit_checked += 1
+                        ref = auditor.check(t.program, t.memory, t.cfg,
+                                            rmem, rtag, runner.mode)
+                        if ref is not None:
+                            stats.audit_corrected += 1
+                            self._breaker.record_failure(
+                                (ticket_key(t), btier)) and self._note_open()
+                            rmem, rregs, rtag = ref
+                    out.append(ServeResult(
+                        memory=rmem, regs=rregs, tag=rtag,
+                        batch_size=n, tier=tier,
+                        trace_fn=trace_fn, kernel=t.kernel))
+                return out
+        return tickets, tier, fin_single if n == 1 else fin_batch
 
 
 def ticket_key(t: Ticket) -> tuple:
