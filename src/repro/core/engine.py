@@ -671,6 +671,7 @@ class EngineCacheInfo:
     vm_signatures: int         # distinct VM executables alive
     vm_hits: int               # VM executor-cache hits
     vm_xla_compiles: int       # distinct VM XLA compilations (incl. batch)
+    instr_hashes: int          # Instr hashes computed (first uses only)
     # cache_tag -> {"hits": n, "misses": n} for target-tagged compiles
     # (docs/TARGETS.md); untagged compiles count only in the totals above.
     per_target: Dict[str, Dict[str, int]] = dataclasses.field(
@@ -682,7 +683,10 @@ def cache_info() -> EngineCacheInfo:
     signature-keyed executable cache — the observability handle for the
     "compile the machine once" contract (docs/ENGINE.md).  ``per_target``
     breaks the LRU counters down by compile tag, one per registered
-    :mod:`repro.targets` target that has compiled anything."""
+    :mod:`repro.targets` target that has compiled anything.
+    ``instr_hashes`` counts the instruction hashes computed since the
+    process started: each :class:`~repro.core.isa.Instr` computes its
+    hash once, so a lookup of a program seen before adds nothing."""
     v = _vm_cache_info()
     with _CACHE_LOCK:
         per_target = {tag: {"hits": c[0], "misses": c[1]}
@@ -692,7 +696,7 @@ def cache_info() -> EngineCacheInfo:
         program_evictions=_EVICTIONS, program_size=len(_CACHE),
         vm_fallbacks=_VM_FALLBACKS, vm_signatures=v.signatures,
         vm_hits=v.hits, vm_xla_compiles=v.xla_compiles,
-        per_target=per_target)
+        instr_hashes=isa.instr_hashes(), per_target=per_target)
 
 
 def _attach_kernel(cp: CompiledProgram, kernel) -> CompiledProgram:

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import operator
 from typing import Iterable, Optional, Tuple
 
 MAX_DIMS = 4
@@ -180,6 +181,41 @@ class Instr:
 
     def is_config(self) -> bool:
         return self.op in CONFIG_OPS
+
+    def __hash__(self) -> int:
+        # The value the generated hash gives, computed on first use and
+        # kept on the object: every program-keyed cache hashes whole
+        # programs, and the generated hash rebuilds a 14-field tuple and
+        # hashes both enums in Python on every call.  Enum hashes are
+        # per-process (string hashing is randomised), so the kept value
+        # is dropped by __getstate__ and never pickled or copied.
+        global _INSTR_HASHES
+        try:
+            return self._hash
+        except AttributeError:
+            h = hash(_instr_fields(self))
+            object.__setattr__(self, "_hash", h)
+            _INSTR_HASHES += 1
+            return h
+
+    def __getstate__(self):
+        state = self.__dict__.copy()
+        state.pop("_hash", None)
+        return state
+
+
+#: The dataclass fields in declaration order, as one tuple (the tuple the
+#: generated ``__hash__`` would hash).
+_instr_fields = operator.attrgetter(
+    *(f.name for f in dataclasses.fields(Instr)))
+_INSTR_HASHES = 0
+
+
+def instr_hashes() -> int:
+    """How many :class:`Instr` hashes were computed since the process
+    started: one per instruction object on its first hash, none for the
+    later ones (``engine.cache_info().instr_hashes``)."""
+    return _INSTR_HASHES
 
 
 # ---------------------------------------------------------------------------
