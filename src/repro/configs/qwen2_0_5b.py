@@ -5,7 +5,7 @@ CONFIG = ModelConfig(
     name="qwen2-0.5b", family="dense",
     num_layers=24, d_model=896, num_heads=14, num_kv_heads=2,
     d_ff=4864, vocab_size=151936, head_dim=64,
-    activation="silu", qkv_bias=True, rope_theta=1e6,
+    activation="silu", qkv_bias=True, rope_theta=1e6, norm_eps=1e-6,
 )
 
 

@@ -37,6 +37,10 @@ RESOLVE = "sched.resolve"      # building ServeResults, resolving tickets
 # On the thread that calls compile_program, nested.
 LOWER = "engine.lower"         # a program-cache miss: compile walk + lowering
 TABLES = "vm.tables"           # the VM's table lowering, padding and upload
+# On a client thread of the decode-step runner (repro.nn.qwen2), nested.
+NN_STEP = "nn.step"            # one session's decode step
+NN_LAYER = "nn.layer"          # one layer of it (``index``)
+NN_STAGE = "nn.stage"          # one stage: submit its tiles, wait for all
 
 _OFF = contextlib.nullcontext()
 _TRANSFERS = contextvars.ContextVar("transfers", default=None)

@@ -11,6 +11,10 @@ end-to-end on every registered target (docs/MODELS.md).
   kernels  — the zoo: six block-kernel factories returning
              :class:`BlockRun` (kernel + memory + oracle check)
   blocks   — per-layer workload assembly from repro.configs models
+             (first-order pricing)
+  qwen2    — Qwen2 decode steps served as chained tile programs through
+             MVEScheduler, with the per-session KV cache
+  qwen2_ref — its plain jax.numpy float32 reference
 """
 from .kernels import (ATTN_ATOL, ATTN_RTOL, BLOCK_KERNELS,
                       MULTIDIM_BLOCKS, BlockRun, attn_tile, gemm_tile,

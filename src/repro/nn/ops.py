@@ -21,6 +21,14 @@ oracle/conformance discipline of the rest of the stack:
 * :func:`tree_reduce_dim0` — log-tree reduction over dimension 0 via a
   scratch region: each step loads two halves with a per-row CR stride
   and combines, leaving one value per top-dim row.
+* :func:`recip_wide` / :func:`rsqrt_wide` — ``1/s`` and ``1/sqrt(s)``
+  for ``s`` in ``[2**-63, 2**63]``: greedy predicated power-of-two
+  scaling both ways into ``[1, 2)`` (``[1, 4)`` for the square root),
+  then Newton–Raphson (RMSNorm and per-token int8 quantisation).
+* :func:`sigmoid_approx` — ``1/(1+exp(-x))`` over the whole real line
+  from :func:`exp_approx` of ``-|x|`` (SiLU).
+* :func:`round_even` — round to the nearest integer, ties to even, by
+  the float32 magic-number add (int8 quantisation).
 
 Every helper traces through the ordinary :class:`KernelBuilder` API, so
 the emitted programs stay inside the existing ISA/executors/targets —
@@ -144,3 +152,96 @@ def tree_reduce_dim0(b: KernelBuilder, src: Operand, dst: Operand,
             raise ValueError(f"unknown reduce op {op!r}")
         dst.at(0, 0).store(va, SEQ, CR)
         cur, length = dst, halfn
+
+
+#: Greedy scaling exponents of the wide reciprocal / square root: their
+#: sum (63) bounds the input range to ``[2**-63, 2**63]`` each way.
+_WIDE_STEPS = (32, 16, 8, 4, 2, 1)
+
+#: 1.5 * 2**23: adding and subtracting it rounds a float32 of magnitude
+#: below 2**22 to the nearest integer, ties to even.
+_ROUND_MAGIC = 12582912.0
+
+
+def _scale_into(b: KernelBuilder, y: VectorHandle, base: float,
+                mirror: float) -> VectorHandle:
+    """Scale ``y`` (in place) into ``[1, base)`` by predicated multiplies
+    with powers of ``base``, mirroring each factor ``base**k`` into the
+    returned register as ``mirror**k``: for ``y`` in
+    ``[base**-63, base**63]`` the result ``y_red = y * base**j`` lies in
+    ``[1, base)`` and the mirror holds ``mirror**j``."""
+    g = b.const(DType.F, 1.0)
+    for k in _WIDE_STEPS:                   # down: y >= base**k
+        y.gte(b.const(DType.F, float(base) ** k))
+        b.mul(y, b.const(DType.F, float(base) ** -k), predicated=True,
+              in_place=True)
+        b.mul(g, b.const(DType.F, float(mirror) ** -k), predicated=True,
+              in_place=True)
+    for k in _WIDE_STEPS:                   # up: y * base**k < base
+        y.lt(b.const(DType.F, float(base) ** (1 - k)))
+        b.mul(y, b.const(DType.F, float(base) ** k), predicated=True,
+              in_place=True)
+        b.mul(g, b.const(DType.F, float(mirror) ** k), predicated=True,
+              in_place=True)
+    return g
+
+
+def recip_wide(b: KernelBuilder, s: VectorHandle,
+               newton_iters: int = 4) -> VectorHandle:
+    """``1/s`` for ``s`` in ``[2**-63, 2**63]`` (the quantisation scale
+    ``127 / max|x|``).  Scaling by powers of two is exact, so the error is
+    :func:`recip_approx`'s Newton error on ``[1, 2)``: below fp32
+    epsilon after 4 iterations (measured, ``tests/test_nn_qwen2.py``)."""
+    y = s.copy()
+    g = _scale_into(b, y, 2.0, 2.0)
+    two = b.const(DType.F, 2.0)
+    rn = b.const(DType.F, 2.0 / 3.0)
+    for _ in range(newton_iters):
+        t = b.sub(two, y * rn)              # 2 - y*r
+        rn *= t
+    return rn * g
+
+
+def rsqrt_wide(b: KernelBuilder, s: VectorHandle,
+               newton_iters: int = 4) -> VectorHandle:
+    """``1/sqrt(s)`` for ``s`` in ``[4**-31, 4**31]`` (RMSNorm's
+    ``mean(x**2) + eps``).  Powers of four scale ``s`` into ``[1, 4)``
+    while the mirror collects the matching powers of two; Newton's
+    ``r <- r * (1.5 - 0.5 * y * r**2)`` then starts from the chord
+    ``r0 = (7 - y) / 6`` (relative error at most 19 %), and 4 iterations
+    reach fp32 rounding (measured relative error ~1e-7)."""
+    y = s.copy()
+    g = _scale_into(b, y, 4.0, 2.0)
+    r = b.sub(b.const(DType.F, 7.0 / 6.0), y * (1.0 / 6.0))
+    for _ in range(newton_iters):
+        t = r * r
+        t *= y
+        t *= 0.5
+        t = b.sub(b.const(DType.F, 1.5), t)
+        r *= t
+    return r * g
+
+
+def sigmoid_approx(b: KernelBuilder, x: VectorHandle) -> VectorHandle:
+    """``1 / (1 + exp(-x))`` for every real ``x``.
+
+    ``t = exp(-|x|)`` lies in :func:`exp_approx`'s domain, ``s = 1/(1+t)``
+    is :func:`recip_approx` on ``[1, 2]``; the result is ``s`` where
+    ``x >= 0`` and ``t * s`` (not ``1 - s``, which cancels) where
+    ``x < 0``, selected by a factor set under the Tag latch before the
+    reciprocal (which rewrites the latch).  Relative error ~1e-6 over ``[-60, 60]``; below -60 the
+    clamp of :func:`exp_approx` leaves an absolute error under 1e-26."""
+    zero = b.const(DType.F, 0.0)
+    t = exp_approx(b, x.min(b.sub(zero, x)))    # exp(-|x|)
+    x.gte(zero)                                 # Tag := x >= 0
+    f = t.copy()                                # f := 1 where x >= 0
+    b.mul(f, zero, predicated=True, in_place=True)
+    b.add(f, b.const(DType.F, 1.0), predicated=True, in_place=True)
+    return recip_approx(b, t + 1.0, max_val=2.0) * f
+
+
+def round_even(b: KernelBuilder, x: VectorHandle) -> VectorHandle:
+    """``x`` rounded to the nearest integer, ties to even, for
+    ``|x| < 2**22``: the float32 add of 1.5 * 2**23 drops the fraction
+    under round-to-nearest-even, the subtract restores the magnitude."""
+    return (x + _ROUND_MAGIC) - _ROUND_MAGIC

@@ -133,3 +133,30 @@ def test_qwen2_pallas_prefill(one_chip):
         params, {"tokens": tokens, "positions": tokens}).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info[0].shape == (1, CONFIG.padded_vocab)
+
+
+# ---------------------------------------------------------------------------
+# Qwen2-0.5B decode tiles on the fused tier, at the published widths.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind,batch", [("attn", 16), ("gemv_d", None)])
+def test_qwen2_decode_tile_fused(one_chip, kind, batch):
+    """The longest decode tile batched 16 (its write-back chain broken by
+    ``engine.FUSE_SPAN`` barriers) and the GEMV tile, named by kind."""
+    from repro.configs.qwen2_0_5b import CONFIG
+    from repro.core.engine import compile_program
+    from repro.nn import qwen2
+    sh = qwen2.Shapes.of(CONFIG, split=512, max_splits=16)
+    tile = qwen2.tiles(sh)[kind]
+    cp = compile_program(tile.program, mode="fused")
+    masks, zeros = cp._fused_operands()
+    fn, shape = cp._fused_fn, (tile.kernel.plan.size,)
+    if batch is not None:
+        fn = jax.vmap(fn, in_axes=(0, None, None))
+        shape = (batch,) + shape
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        _on(one_chip, shape, jnp.float32),
+        _on(one_chip, masks.shape, masks.dtype),
+        _on(one_chip, zeros.shape, zeros.dtype)).compile()
+    assert compiled.as_text().startswith(f"HloModule jit__execute.dec_{kind}")
+    assert compiled.out_info[0].shape == shape
