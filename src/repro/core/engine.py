@@ -538,16 +538,26 @@ class CompiledProgram:
 
     def run_batch_async(self, memories):
         """Dispatch a batched execution without blocking (see
-        :meth:`run_async`); finalize with :meth:`finalize_batch`."""
+        :meth:`run_async`); finalize with :meth:`finalize_batch`.
+
+        ``memories`` may also be a list of device images (``jax.Array``):
+        one jitted stack puts them into a fresh buffer on the device,
+        which the fused executable takes as its donated operand, so they
+        are neither copied to the host nor copied twice."""
         fire_fault_hook("engine.dispatch", tier=self.mode)
         try:
             if isinstance(memories, dict):
                 memories = self._bound_kernel().pack_batch(memories)
+            stacked = isinstance(memories, list) and all(
+                isinstance(m, jax.Array) for m in memories)
+            if stacked:
+                memories = _stack_images(tuple(memories))
             if self._use_vm(memories):
                 return ("vm", self._vm.run_batch_async(memories))
             masks, zeros = self._fused_operands()
             mem, regs, tag, _ = self._get_batch_jit()(
-                self._donatable(memories), masks, zeros)
+                memories if stacked else self._donatable(memories), masks,
+                zeros)
             return ("fused", (mem, dict(regs), tag))
         except _PASSTHROUGH:
             raise
@@ -577,13 +587,17 @@ class CompiledProgram:
         one signature-keyed XLA executable.  The key is the VM signature
         bucket for VM-routed requests (program identity rides along:
         batching stacks *memories* under one program) and the program
-        itself for fused-routed ones."""
+        itself for fused-routed ones, and ends with whether the image is
+        a device array."""
         mem = np.asarray(memory) if not hasattr(memory, "shape") else memory
         size = int(mem.shape[-1])
         dtype = str(getattr(mem, "dtype", "float64"))
+        # the last element keeps device images and host images apart: a
+        # batch is stacked on the device or on the host, never both
+        resident = isinstance(memory, jax.Array)
         if self._use_vm(memory):
-            return ("vm", self._vm._signature(size), size, dtype)
-        return ("fused", id(self), size, dtype)
+            return ("vm", self._vm._signature(size), size, dtype, resident)
+        return ("fused", id(self), size, dtype, resident)
 
     def _get_batch_jit(self) -> AotJit:
         if self._batch_jit is None:
@@ -619,6 +633,30 @@ class CompiledProgram:
             self._get_batch_jit().warmup(mem, masks, zeros)
         return self
 
+    def warmup_resident(self, memory_size: int,
+                        batches: Tuple[int, ...] = ()) -> "CompiledProgram":
+        """Compile, without running the program, what the fused tier
+        takes beyond the program's own executables to serve device
+        images of ``memory_size`` words: the copy a singleton makes of its
+        image and, for each size in ``batches``, the stack of that many
+        images (:meth:`run_batch_async`), the split of the batch's
+        memories (:func:`split_rows`) and the read of one request's
+        registers and Tag (:func:`take_row`).  Returns ``self``."""
+        image = jnp.zeros(memory_size, jnp.float32)
+        jax.block_until_ready(self._donatable(image))
+        if not batches:
+            return self
+        masks, zeros = self._fused_operands()
+        _, regs, tag, _ = jax.eval_shape(self._fused_fn, image, masks,
+                                         zeros)
+        for b in batches:
+            state = jax.tree.map(lambda a: jnp.zeros((b,) + a.shape, a.dtype),
+                                 (dict(regs), tag))
+            stacked = _stack_images((image,) * b)
+            jax.block_until_ready((split_rows(stacked),
+                                   take_row(state, 0)))
+        return self
+
     def _finalize_trace(self, rand_addrs) -> List[TraceEvent]:
         trace: List[TraceEvent] = []
         for step in self.steps:
@@ -637,6 +675,30 @@ class CompiledProgram:
         """The compile-time trace; exact unless the program uses
         random-base accesses (then run() fills the ``lines`` fields)."""
         return [s.event for s in self.steps]
+
+
+# ---------------------------------------------------------------------------
+# Device images: a batch of ``jax.Array`` memory images is stacked, run and
+# split on the device (docs/SERVING.md "Device images").
+# ---------------------------------------------------------------------------
+
+@jax.jit
+def _stack_images(images):
+    return jnp.stack(images)
+
+
+@jax.jit
+def split_rows(batch):
+    """The rows of a batched device array as device arrays of their own,
+    in one jitted call."""
+    return [batch[i] for i in range(batch.shape[0])]
+
+
+@jax.jit
+def take_row(tree, row):
+    """Row ``row`` of every array in ``tree``; the row is traced, so one
+    executable serves every row."""
+    return jax.tree.map(lambda a: a[row], tree)
 
 
 #: A fused program gets an optimization barrier on its memory, registers

@@ -12,11 +12,15 @@ host-to-device and device-to-host copies.  Inside :func:`counting`, they
 add the bytes copied to the given object's integer ``h2d_bytes`` and
 ``d2h_bytes`` (the serve cycle passes its
 :class:`repro.runtime.scheduler.SchedulerStats`), on that thread only.
+:func:`add_copies` adds copies made elsewhere (a client thread's own
+uploads and fetches) to the same counts; every addition holds one lock,
+so counts from several threads add up.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +48,7 @@ NN_STAGE = "nn.stage"          # one stage: submit its tiles, wait for all
 
 _OFF = contextlib.nullcontext()
 _TRANSFERS = contextvars.ContextVar("transfers", default=None)
+_COUNT_LOCK = threading.Lock()
 
 
 def span(name: str, **meta):
@@ -64,6 +69,14 @@ def counting(transfers):
         _TRANSFERS.reset(token)
 
 
+def add_copies(transfers, h2d: int = 0, d2h: int = 0) -> None:
+    """Add ``h2d`` and ``d2h`` bytes to ``transfers``' counts, from any
+    thread."""
+    with _COUNT_LOCK:
+        transfers.h2d_bytes += h2d
+        transfers.d2h_bytes += d2h
+
+
 def to_device(x) -> jax.Array:
     """A fresh device buffer holding ``x`` (the executables donate their
     memory operand, so it must be jax-owned); counts the bytes uploaded
@@ -71,7 +84,7 @@ def to_device(x) -> jax.Array:
     out = jnp.array(x, copy=True)
     transfers = _TRANSFERS.get()
     if transfers is not None and not isinstance(x, jax.Array):
-        transfers.h2d_bytes += out.nbytes
+        add_copies(transfers, h2d=out.nbytes)
     return out
 
 
@@ -80,5 +93,5 @@ def to_host(x) -> np.ndarray:
     array."""
     transfers = _TRANSFERS.get()
     if transfers is not None and isinstance(x, jax.Array):
-        transfers.d2h_bytes += x.nbytes
+        add_copies(transfers, d2h=x.nbytes)
     return np.asarray(x)

@@ -842,7 +842,7 @@ class VMProgram:
                 self.scat_bucket)
 
     def _pad_memory(self, memory, bucket: int) -> np.ndarray:
-        mem = np.asarray(memory)
+        mem = to_host(memory)       # a device image is fetched, and counted
         buf = np.zeros(mem.shape[:-1] + (bucket + self.cfg.lanes,),
                        dtype=np.float32)
         buf[..., : mem.shape[-1]] = mem
@@ -859,7 +859,7 @@ class VMProgram:
         many executions back to back and pay one sync, instead of a
         host round trip per request."""
         fire_fault_hook("vm.dispatch", tier="vm")
-        mem_size = np.asarray(memory).shape[0]
+        mem_size = np.shape(memory)[0]
         sig = self._signature(mem_size)
         ex = _executor(sig)
         # A fresh buffer: the executable donates (and therefore writes
@@ -891,7 +891,7 @@ class VMProgram:
         """Batched :meth:`run_async`: one vmapped dispatch over a leading
         batch of memory images; finalize with :meth:`finalize_batch`."""
         fire_fault_hook("vm.dispatch", tier="vm")
-        mems = np.asarray(memories)
+        mems = to_host(memories)
         mem_size = mems.shape[-1]
         sig = self._signature(mem_size)
         ex = _executor(sig)

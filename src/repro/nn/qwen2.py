@@ -4,7 +4,9 @@ A decode step of one session (one token through the layers and the final
 RMSNorm) becomes a chain of *stages*; each stage submits its tiles through
 :meth:`repro.runtime.scheduler.MVEScheduler.submit`, waits for every
 answer, and writes the outputs into the next stage's memory images.  The
-per-session KV cache lives here, on the host.  The plain reference is
+per-session KV cache lives here, on the host; each session's GEMV tile
+images, weights in, live on the device (:class:`Session`).  The plain
+reference is
 :mod:`repro.nn.qwen2_ref`; docs/MODELS.md ("Qwen2-0.5B on the serving
 path") has the tile grid.
 
@@ -47,6 +49,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.engine import name_program
@@ -420,10 +424,38 @@ class Tile:
     def read(self, memory, name: str) -> np.ndarray:
         return np.array(np.asarray(memory)[self.region(name)], np.float32)
 
+    # -- a GEMV kind's device images (Qwen2Decoder) -----------------------
+    @functools.cached_property
+    def write_input(self):
+        """One jitted update of a tuple of this GEMV kind's device
+        images: the first ``len(xq)`` words of ``x`` set to ``xq`` and
+        ``sx`` to ``sx``.  Returns new images; the given ones are left as
+        they are."""
+        x, s = self.region("x").start, self.region("sx").start
+
+        def write_input(images, xq, sx):
+            return tuple(img.at[x:x + xq.shape[0]].set(xq).at[s:s + 1]
+                         .set(sx) for img in images)
+        return jax.jit(write_input)
+
+    @functools.cached_property
+    def gather_y(self):
+        """One jitted gather of the ``y`` regions of a tuple of device
+        answers, concatenated in order."""
+        y = self.region("y")
+
+        def gather_y(memories):
+            return jnp.concatenate([m[y] for m in memories])
+        return jax.jit(gather_y)
+
 
 #: The tile kinds of a decode step: ``gemv_d`` contracts over the hidden
 #: width (q, k, v, o, gate, up), ``gemv_ff`` over the intermediate (down).
 KINDS = ("norm", "gemv_d", "rope", "attn", "merge", "silu_mul", "gemv_ff")
+
+#: The GEMV stages of a layer, each the projections of one input vector.
+GEMV_STAGES = {"qkv": ("q", "k", "v"), "o": ("o",),
+               "gate_up": ("gate", "up"), "down": ("down",)}
 
 
 @functools.lru_cache(maxsize=8)
@@ -523,20 +555,20 @@ class DecodeStats:
 
 class Session:
     """One session's KV cache: per layer ``(kv_heads, capacity, hd)``
-    keys and values, the first ``length`` positions written; and its own
-    GEMV tile images (``gemv[layer][projection]``: ``(tile, image)``
-    pairs, weights in place), whose inputs each step rewrites in place
-    once the previous step's tickets have resolved, so a step copies no
-    weights on the host."""
+    keys and values on the host, the first ``length`` positions written;
+    and its own GEMV tile images on the device (``gemv[layer][stage]``:
+    a tuple of device arrays, weights in, one a tile of
+    :data:`GEMV_STAGES`' projections in order).  Each step replaces a
+    stage's tuple by one with that step's input written in (never
+    writing into the old images), so no weight crosses to the device
+    after the session is made."""
 
-    def __init__(self, sh: Shapes, gemv: List[Dict[str, list]]):
+    def __init__(self, sh: Shapes, gemv: List[Dict[str, tuple]]):
         shape = (len(gemv), sh.kv_heads, sh.capacity, sh.head_dim)
         self.k = np.zeros(shape, np.float32)
         self.v = np.zeros(shape, np.float32)
         self.length = 0
-        self.gemv = [{name: [(t, img.copy()) for t, img in templates]
-                      for name, templates in layer.items()}
-                     for layer in gemv]
+        self.gemv = gemv
 
     def history(self, layer: int, upto: int):
         """Keys and values of positions ``[0, upto)`` as
@@ -565,15 +597,34 @@ class StepOutput:
                 "final": self.final, "quant": self.quant}
 
 
+@dataclasses.dataclass(frozen=True)
+class GemvStage:
+    """One GEMV stage of a layer: its tile kind, contraction, each
+    projection's output width, and one host image a tile, weights in
+    (what each session uploads)."""
+
+    tile: Tile
+    k: int
+    widths: Tuple[int, ...]
+    images: Tuple[np.ndarray, ...]
+
+
 class Qwen2Decoder:
     """Decode steps of a Qwen2 stack through an :class:`MVEScheduler`.
 
     ``params`` are :func:`init_params`'s (any number of layers).  Each
     :meth:`step` is one token of one session; steps of different sessions
     may run at once from different threads.  ``on_tile(kind, program,
-    image, result)`` sees every answered tile; a GEMV tile's ``image`` is
-    the session's own and is rewritten by its next step, so a caller that
-    keeps it copies it."""
+    image, result)`` sees every answered tile.  ``image`` is the image
+    submitted: a numpy array, or for a GEMV tile one of the session's
+    device images (``jax.Array``, see :class:`Session`), whose answer's
+    memory, registers and Tag are device arrays too.
+
+    The constructor compiles what the GEMV path adds to the tiles' own
+    executables (each stage's input update and ``y`` gather, the
+    scheduler's device stack and split at every batch bucket), so a step
+    compiles nothing more; the host-device copies the runner makes
+    itself count in the scheduler's ``h2d_bytes`` / ``d2h_bytes``."""
 
     def __init__(self, sh: Shapes, params: dict, sched,
                  on_tile: Optional[Callable] = None):
@@ -585,9 +636,8 @@ class Qwen2Decoder:
         self.stats = DecodeStats()
         self._lock = threading.Lock()
         self.n_layers = len(params["layers"])
-        self._gemv = [{name: self._gemv_templates(lp[name])
-                       for name in ("q", "k", "v", "o", "gate", "up",
-                                    "down")}
+        self._gemv = [{stage: self._gemv_stage_of([lp[p] for p in projs])
+                       for stage, projs in GEMV_STAGES.items()}
                       for lp in params["layers"]]
         norm = self.tiles["norm"]
         self._norm = []
@@ -596,27 +646,55 @@ class Qwen2Decoder:
             img = norm.zeros.copy()
             img[norm.region("gamma")] = gamma
             self._norm.append(img)
+        self._warm()
 
-    def _gemv_templates(self, p) -> List[Tuple[Tile, np.ndarray]]:
-        """One image per tile of ``mt`` output channels, weights in."""
-        k, m = p["w"].shape
+    def _gemv_stage_of(self, projections) -> GemvStage:
+        """The stage of ``projections`` (one input): one image per tile of
+        ``mt`` output channels, weights in."""
+        k = projections[0]["w"].shape[0]
         if k not in (self.sh.d, self.sh.ff):
             raise ValueError(f"a projection over {k} rows: GEMV tiles "
                              f"contract over {self.sh.d} or {self.sh.ff}")
         t = self.tiles["gemv_d" if k == self.sh.d else "gemv_ff"]
         mt = self.sh.mt
-        out = []
-        for j in range(0, m, mt):
-            img = t.zeros.copy()
-            w = img[t.region("w")].reshape(-1, mt)
-            w[:k] = p["w"][:, j:j + mt]
-            img[t.region("ws")] = p["s"][j:j + mt]
-            img[t.region("bias")] = p["b"][j:j + mt]
-            out.append((t, img))
-        return out
+        widths, images = [], []
+        for p in projections:
+            m = p["w"].shape[1]
+            widths.append(m)
+            for j in range(0, m, mt):
+                img = t.zeros.copy()
+                w = img[t.region("w")].reshape(-1, mt)
+                w[:k] = p["w"][:, j:j + mt]
+                img[t.region("ws")] = p["s"][j:j + mt]
+                img[t.region("bias")] = p["b"][j:j + mt]
+                images.append(img)
+        return GemvStage(t, k, tuple(widths), tuple(images))
+
+    def _warm(self) -> None:
+        """Compile every executable the GEMV path adds, before the first
+        step: each stage's input update and ``y`` gather (the stages have
+        the same shapes in every layer), and the scheduler's device stack
+        and split of each GEMV kind at every batch bucket."""
+        for st in (self._gemv[0].values() if self._gemv else ()):
+            t = st.tile
+            images = (jnp.zeros(t.zeros.size, jnp.float32),) * len(st.images)
+            jax.block_until_ready(t.gather_y(t.write_input(
+                images, np.zeros(st.k, np.float32),
+                np.zeros(1, np.float32))))
+        for kind in ("gemv_d", "gemv_ff"):
+            t = self.tiles[kind]
+            self.sched.warm_resident(t.program, t.zeros.size)
 
     def new_session(self) -> Session:
-        return Session(self.sh, self._gemv)
+        """A session with an empty cache and its own GEMV images uploaded
+        to the device (about 242 MiB at Qwen2-0.5B's widths for 4 layers;
+        counted in the scheduler's ``h2d_bytes``)."""
+        gemv = [{stage: tuple(jnp.array(img) for img in st.images)
+                 for stage, st in layer.items()} for layer in self._gemv]
+        self.sched.count_copies(h2d=sum(
+            img.nbytes for layer in self._gemv for st in layer.values()
+            for img in st.images))
+        return Session(self.sh, gemv)
 
     # -- stages ----------------------------------------------------------
     def _stage(self, kind: str, jobs: List[Tuple[Tile, np.ndarray]]
@@ -646,20 +724,20 @@ class Qwen2Decoder:
         return (t.read(mem, "h_out"), t.read(mem, "x"), t.read(mem, "xq"),
                 t.read(mem, "sx"))
 
-    def _gemv_stage(self, kind: str, parts, xq, sx) -> List[np.ndarray]:
-        """The projections ``parts`` (a session's lists of images) of one
-        input, written into the images in place: one output vector per
-        part."""
-        jobs = []
-        for templates in parts:
-            for t, img in templates:
-                img[t.region("x")][:len(xq)] = xq
-                img[t.region("sx")] = sx
-                jobs.append((t, img))
-        mems = iter(self._stage(kind, jobs))
-        return [np.concatenate([t.read(next(mems), "y")
-                                for t, _ in templates])
-                for templates in parts]
+    def _gemv_stage(self, kind: str, sess: Session, layer: int, xq, sx
+                    ) -> List[np.ndarray]:
+        """GEMV stage ``kind`` of ``layer``: ``xq`` and ``sx`` written into
+        the session's device images by one jitted update, every tile
+        served, and the ``y`` of every answer gathered and copied to the
+        host at once; one output vector per projection."""
+        st = self._gemv[layer][kind]
+        t = st.tile
+        images = t.write_input(sess.gemv[layer][kind], xq, sx)
+        sess.gemv[layer][kind] = images
+        mems = self._stage(kind, [(t, img) for img in images])
+        y = np.asarray(t.gather_y(tuple(mems)))
+        self.sched.count_copies(h2d=xq.nbytes + sx.nbytes, d2h=y.nbytes)
+        return np.split(y, np.cumsum(st.widths)[:-1])
 
     def _attention(self, sess: Session, layer: int, q, n: int) -> Tuple:
         """Split tiles over positions ``[0, n)`` and their merge: the
@@ -709,15 +787,14 @@ class Qwen2Decoder:
         delta = np.zeros(sh.d, np.float32)
         out = StepOutput(pos, [], [], [], None)
         with span(NN_STEP, pos=pos):
-            for li, w in enumerate(sess.gemv):
+            for li in range(self.n_layers):
                 with span(NN_LAYER, index=li):
                     h, _, xq, sx = self._norm_stage(2 * li, h, delta)
                     if li:
                         out.layers.append(h)
                     quant = {"x": (xq, sx)}
                     out.quant.append(quant)
-                    q, k, v = self._gemv_stage(
-                        "qkv", [w["q"], w["k"], w["v"]], xq, sx)
+                    q, k, v = self._gemv_stage("qkv", sess, li, xq, sx)
                     img = rt.zeros.copy()
                     img[rt.region("q")] = q
                     img[rt.region("k")] = k
@@ -733,18 +810,17 @@ class Qwen2Decoder:
                     oq, so = self._attention(sess, li,
                                              rt.read(mem, "q_out"), pos + 1)
                     quant["o"] = (oq, so)
-                    a, = self._gemv_stage("o", [w["o"]], oq, so)
+                    a, = self._gemv_stage("o", sess, li, oq, so)
                     h, _, xq, sx = self._norm_stage(2 * li + 1, h, a)
                     quant["x2"] = (xq, sx)
-                    gate, up = self._gemv_stage("gate_up",
-                                                [w["gate"], w["up"]], xq, sx)
+                    gate, up = self._gemv_stage("gate_up", sess, li, xq, sx)
                     st = self.tiles["silu_mul"]
                     img = st.zeros.copy()
                     img[st.region("gate")] = gate
                     img[st.region("up")] = up
                     mem, = self._stage("silu_mul", [(st, img)])
                     quant["m"] = (st.read(mem, "mq"), st.read(mem, "sm"))
-                    delta, = self._gemv_stage("down", [w["down"]],
+                    delta, = self._gemv_stage("down", sess, li,
                                               *quant["m"])
             h, final, _, _ = self._norm_stage(2 * self.n_layers, h, delta)
         out.layers.append(h)

@@ -30,6 +30,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..core.tracing import to_host
+
 AuditReference = Tuple[np.ndarray, Dict[int, np.ndarray], np.ndarray]
 
 
@@ -64,8 +66,8 @@ class ResultAuditor:
         mem, regs, tag = ref
         with self._lock:
             self.checked += 1
-        if np.array_equal(mem, np.asarray(served_memory)) and \
-                np.array_equal(tag, np.asarray(served_tag)):
+        if np.array_equal(mem, to_host(served_memory)) and \
+                np.array_equal(tag, to_host(served_tag)):
             return None
         with self._lock:
             self.mismatches += 1

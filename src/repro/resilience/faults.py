@@ -51,6 +51,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core.tracing import to_host
 from .errors import InjectedFault, InjectedWorkerDeath
 
 KINDS = ("error", "straggler", "bitflip", "kill")
@@ -222,7 +223,7 @@ class FaultInjector:
         flips = self._hit("finalize", rids, tier, collect_bitflips=True)
         if not flips:
             return memory
-        mem = np.array(memory, copy=True)
+        mem = np.array(to_host(memory), copy=True)   # counted if on device
         for spec, rid in flips:
             row = mem if mem.ndim == 1 else mem[rows[rid]] \
                 if rows and rid in rows else mem[0]

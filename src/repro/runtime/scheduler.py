@@ -93,6 +93,10 @@ deterministic chaos schedule against the real scheduler paths —
 injected fault rates and ``tests/test_resilience.py`` replays a seeded
 10 % chaos stream and asserts full recovery.
 
+Device images: a request whose memory image is a ``jax.Array`` is
+stacked, run and answered on the device, its answer's memory, registers
+and Tag device arrays on every path (docs/SERVING.md "Device images").
+
 Results are bit-identical to per-request ``CompiledProgram.run`` (and
 therefore to the stepwise oracle): batching only stacks independent
 memory images along a vmapped axis.  ``tests/test_conformance.py``
@@ -107,14 +111,17 @@ import time
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..core import isa
 from ..core.cost import TraceEvent
-from ..core.engine import CompiledProgram, cache_info, compile_program
+from ..core.engine import (CompiledProgram, cache_info, compile_program,
+                           split_rows, take_row)
 from ..core.machine import MVEConfig, next_pow2
 from ..core.tracing import (DISPATCH, FETCH, GROUP, IDLE, PROMOTE, RESOLVE,
-                            SYNC, WINDOW, counting, span, to_host)
+                            SYNC, WINDOW, add_copies, counting, span,
+                            to_device, to_host)
 from ..resilience.audit import ResultAuditor
 from ..resilience.breaker import CircuitBreaker, RetryPolicy
 from ..resilience.errors import (CancelledError, DeadlineExceededError,
@@ -145,26 +152,50 @@ class ServeResult:
 
     ``trace`` is materialized lazily for batched results (a fresh copy of
     the compile-time static trace): serving loops that never read it pay
-    nothing for it.
+    nothing for it.  So are the answers of a batch of device images
+    (``rows``, a :class:`DeviceRows`): ``memory``, ``regs`` and ``tag``
+    come out of the batch's device outputs when first read, on the
+    reading thread, not on the serve worker's.
     """
 
-    __slots__ = ("memory", "regs", "tag", "batch_size", "tier",
-                 "_trace", "_trace_fn", "kernel", "_operands")
+    __slots__ = ("_memory", "_regs", "_tag", "_rows", "_row", "batch_size",
+                 "tier", "_trace", "_trace_fn", "kernel", "_operands")
 
     def __init__(self, memory: np.ndarray, regs: Dict[int, np.ndarray],
                  tag: np.ndarray, batch_size: int, tier: str,
                  trace: Optional[List[TraceEvent]] = None,
                  trace_fn: Optional[Callable[[], List[TraceEvent]]] = None,
-                 kernel=None):
-        self.memory = memory
-        self.regs = regs
-        self.tag = tag
+                 kernel=None, rows: Optional["DeviceRows"] = None,
+                 row: int = 0):
+        self._memory = memory
+        self._regs = regs
+        self._tag = tag
+        self._rows = rows
+        self._row = row
         self.batch_size = batch_size   # how many requests shared the dispatch
         self.tier = tier               # "vm" | "fused" | "oracle"
         self._trace = trace
         self._trace_fn = trace_fn
         self.kernel = kernel           # frontend Kernel, when submitted as one
         self._operands = None
+
+    @property
+    def memory(self):
+        if self._memory is None:
+            self._memory = self._rows.memory(self._row)
+        return self._memory
+
+    @property
+    def regs(self) -> Dict[int, np.ndarray]:
+        if self._regs is None:
+            self._regs, self._tag = self._rows.state(self._row)
+        return self._regs
+
+    @property
+    def tag(self):
+        if self._tag is None:
+            self._regs, self._tag = self._rows.state(self._row)
+        return self._tag
 
     @property
     def trace(self) -> List[TraceEvent]:
@@ -184,6 +215,30 @@ class ServeResult:
         return (f"ServeResult(tier={self.tier!r}, "
                 f"batch_size={self.batch_size}, "
                 f"memory.shape={tuple(np.shape(self.memory))})")
+
+
+class DeviceRows:
+    """The device outputs of one batched dispatch of device images, read
+    per request on demand: every request's memory by one jitted split of
+    the batch on the first read (:func:`~repro.core.engine.split_rows`),
+    a request's registers and Tag by one jitted read
+    (:func:`~repro.core.engine.take_row`)."""
+
+    def __init__(self, memory, regs, tag):
+        self._memory = memory
+        self._state = (regs, tag)
+        self._split = None
+        self._lock = threading.Lock()
+
+    def memory(self, row: int):
+        with self._lock:
+            if self._split is None:
+                self._split = split_rows(self._memory)
+                self._memory = None
+        return self._split[row]
+
+    def state(self, row: int):
+        return take_row(self._state, row)
 
 
 class Ticket:
@@ -303,6 +358,7 @@ class SchedulerStats:
     queue_wait_s: float = 0.0    # their summed time in the queue
     h2d_bytes: int = 0           # bytes copied host -> device by dispatches
     d2h_bytes: int = 0           # bytes copied device -> host by finalizes
+    resident_requests: int = 0   # device-image requests answered on the device
 
     @property
     def batch_efficiency(self) -> float:
@@ -563,6 +619,24 @@ class MVEScheduler:
 
     def __exit__(self, *exc):
         self.close()
+
+    def count_copies(self, h2d: int = 0, d2h: int = 0) -> None:
+        """Count host-device copies that a client makes itself for its
+        requests (uploads into its device images, fetches of parts of
+        their answers) in ``stats.h2d_bytes`` / ``d2h_bytes``, beside the
+        serve cycle's own; thread-safe."""
+        add_copies(self.stats, h2d, d2h)
+
+    def warm_resident(self, program, memory_size: int) -> None:
+        """Compile, without running ``program``, what serving it from
+        device images of ``memory_size`` words on the fused tier takes
+        beyond its own executables: a singleton's copy of its image and,
+        at each batch bucket up to ``max_batch``, the device stack, the
+        split of the memories and the read of a request's registers and
+        Tag (:meth:`CompiledProgram.warmup_resident`)."""
+        buckets = tuple(1 << i for i in range(1, self.max_batch.bit_length()))
+        compile_program(program, self.cfg, mode="fused").warmup_resident(
+            memory_size, buckets)
 
     def cache_info(self):
         """The engine/VM compile-cache counters this scheduler feeds —
@@ -1001,10 +1075,17 @@ class MVEScheduler:
         self.stats.dispatches += 1
         self.stats.singles += 1
         self.stats.max_batch_seen = max(self.stats.max_batch_seen, 1)
+        resident = isinstance(t.memory, jax.Array)
         if tier == "oracle":
             self.stats.oracle_serves += 1
             mem_i, st_i = self._oracle(t.cfg).run_stepwise(
                 list(t.program), t.memory)
+            if resident:
+                mem, regs, tag = self._device_answer(mem_i, st_i.regs,
+                                                     st_i.tag)
+                return ServeResult(memory=mem, regs=regs, tag=tag,
+                                   batch_size=1, tier="oracle",
+                                   trace=st_i.trace, kernel=t.kernel)
             return ServeResult(
                 memory=np.asarray(mem_i),
                 regs={r: np.asarray(v) for r, v in st_i.regs.items()},
@@ -1015,7 +1096,7 @@ class MVEScheduler:
                 mem_j, state = runner.run(t.memory)
         else:
             mem_j, state = runner.run(t.memory)
-        mem = to_host(mem_j)
+        mem = mem_j if resident else to_host(mem_j)
         regs, tag = state.regs, state.tag
         if self._auditor is not None and self._auditor.should_audit(t.rid):
             self.stats.audit_checked += 1
@@ -1026,9 +1107,18 @@ class MVEScheduler:
                 self._breaker.record_failure((ctx.key, tier)) and \
                     self._note_open()
                 mem, regs, tag = ref
+        if resident:
+            mem, regs, tag = self._device_answer(mem, regs, tag)
         return ServeResult(memory=mem, regs=regs, tag=tag, batch_size=1,
                            tier=runner.mode, trace=state.trace,
                            kernel=t.kernel)
+
+    def _device_answer(self, mem, regs, tag):
+        """A device request's answer as device arrays; one that never
+        left the device counts in ``resident_requests``."""
+        mem, regs, tag, stayed = _device_arrays(mem, regs, tag)
+        self.stats.resident_requests += stayed
+        return mem, regs, tag
 
     def _oracle(self, cfg: MVEConfig):
         o = self._oracles.get(cfg)
@@ -1087,6 +1177,9 @@ class MVEScheduler:
         rids = [t.rid for t in tickets]
         auditor = self._auditor
         stats = self.stats
+        # one group holds device images only or host images only
+        # (``batch_group_key``)
+        resident = isinstance(tickets[0].memory, jax.Array)
         with span(DISPATCH, tier=tier, n=n, rows=rows):
             if inj is not None:
                 inj.dispatch(rids, btier, shielded=shielded)
@@ -1096,9 +1189,12 @@ class MVEScheduler:
                 pending = runner.run_async(tickets[0].memory)
                 stats.singles += 1
             else:
-                mems = [np.asarray(t.memory) for t in tickets]
-                stacked = np.stack(mems + [mems[0]] * (rows - n))
-                pending = runner.run_batch_async(stacked)
+                mems = [t.memory if resident else np.asarray(t.memory)
+                        for t in tickets]
+                mems += [mems[0]] * (rows - n)
+                # the engine stacks a list of device images on the device
+                pending = runner.run_batch_async(
+                    mems if resident else np.stack(mems))
                 stats.batched_requests += n
                 if tier == "fused":
                     stats.fused_batches += 1
@@ -1112,7 +1208,8 @@ class MVEScheduler:
                 runner.wait(pending)
             with span(FETCH):
                 mem, state = runner.finalize_run(pending)
-                mem = to_host(mem)
+                if not resident:
+                    mem = to_host(mem)
             with span(RESOLVE):
                 t = tickets[0]
                 regs, tag = state.regs, state.tag
@@ -1127,6 +1224,8 @@ class MVEScheduler:
                         self._breaker.record_failure(
                             (ticket_key(t), btier)) and self._note_open()
                         mem, regs, tag = ref
+                if resident:
+                    mem, regs, tag = self._device_answer(mem, regs, tag)
                 return [ServeResult(memory=mem, regs=regs, tag=tag,
                                     batch_size=1, tier=runner.mode,
                                     trace=state.trace, kernel=t.kernel)]
@@ -1136,15 +1235,22 @@ class MVEScheduler:
                 runner.wait(pending)
             with span(FETCH):
                 mem, regs, tag = runner.finalize_batch(pending)
-                # One device->host transfer per array (not per request):
-                # the per-request views below slice host memory.
-                mem = to_host(mem)
-                tag = to_host(tag)
-                regs = {r: to_host(v) for r, v in regs.items()}
+                if not resident:
+                    # One device->host transfer per array (not per
+                    # request): the per-request views below slice host
+                    # memory.
+                    mem = to_host(mem)
+                    tag = to_host(tag)
+                    regs = {r: to_host(v) for r, v in regs.items()}
             with span(RESOLVE):
                 if inj is not None and not shielded:
                     rows_of = {t.rid: b for b, t in enumerate(tickets)}
                     mem = inj.finalize(rids, btier, mem, rows_of)
+                stayed = False
+                if resident:
+                    mem, regs, tag, stayed = _device_arrays(mem, regs, tag)
+                    # split when the answers are read, off this thread
+                    rows_out = DeviceRows(mem, regs, tag)
 
                 def trace_fn():
                     # Deferred static_trace access too: unread traces
@@ -1155,24 +1261,50 @@ class MVEScheduler:
                 out = []
                 for b in range(n):
                     t = tickets[b]
-                    rmem = mem[b]
-                    rregs = {r: v[b] for r, v in regs.items()}
-                    rtag = tag[b]
+                    if resident:
+                        res = ServeResult(
+                            memory=None, regs=None, tag=None, batch_size=n,
+                            tier=tier, trace_fn=trace_fn, kernel=t.kernel,
+                            rows=rows_out, row=b)
+                    else:
+                        res = ServeResult(
+                            memory=mem[b],
+                            regs={r: v[b] for r, v in regs.items()},
+                            tag=tag[b], batch_size=n, tier=tier,
+                            trace_fn=trace_fn, kernel=t.kernel)
+                    kept = stayed
                     if auditor is not None and auditor.should_audit(t.rid):
                         stats.audit_checked += 1
                         ref = auditor.check(t.program, t.memory, t.cfg,
-                                            rmem, rtag, runner.mode)
+                                            res.memory, res.tag, runner.mode)
                         if ref is not None:
                             stats.audit_corrected += 1
                             self._breaker.record_failure(
                                 (ticket_key(t), btier)) and self._note_open()
                             rmem, rregs, rtag = ref
-                    out.append(ServeResult(
-                        memory=rmem, regs=rregs, tag=rtag,
-                        batch_size=n, tier=tier,
-                        trace_fn=trace_fn, kernel=t.kernel))
+                            if resident:
+                                rmem, rregs, rtag, kept = _device_arrays(
+                                    rmem, rregs, rtag)
+                            res = ServeResult(
+                                memory=rmem, regs=rregs, tag=rtag,
+                                batch_size=n, tier=tier, trace_fn=trace_fn,
+                                kernel=t.kernel)
+                    stats.resident_requests += kept
+                    out.append(res)
                 return out
         return tickets, tier, fin_single if n == 1 else fin_batch
+
+
+def _device_arrays(mem, regs, tag):
+    """``(mem, regs, tag, stayed)``: an answer's arrays on the device,
+    what a path left on the host uploaded (and counted); ``stayed`` is
+    whether nothing was."""
+    if all(isinstance(a, jax.Array) for a in (mem, tag, *regs.values())):
+        return mem, regs, tag, True
+
+    def up(a):
+        return a if isinstance(a, jax.Array) else to_device(a)
+    return up(mem), {r: up(v) for r, v in regs.items()}, up(tag), False
 
 
 def ticket_key(t: Ticket) -> tuple:

@@ -22,6 +22,7 @@ layer's output, the appended keys and values and the final norm):
 """
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -117,6 +118,92 @@ def test_decode_from_prefilled_cache(params, sched):
             assert forced["quant_step_error"] < 1e-3
             assert forced["scale_error"] < 1e-3
             assert qwen2_ref.compare(out.as_ref(), forced) < TOL
+
+
+class _HostImages(qwen2.Qwen2Decoder):
+    """The runner with each GEMV image built afresh on the host every
+    step from the decoder's templates and sent as a numpy image."""
+
+    def _gemv_stage(self, kind, sess, layer, xq, sx):
+        st = self._gemv[layer][kind]
+        t = st.tile
+        jobs = []
+        for template in st.images:
+            img = template.copy()
+            img[t.region("x")][:len(xq)] = xq
+            img[t.region("sx")] = sx
+            jobs.append((t, img))
+        y = np.concatenate([t.read(m, "y") for m in self._stage(kind, jobs)])
+        return np.split(y, np.cumsum(st.widths)[:-1])
+
+
+def test_resident_images_match_host_images(params, sched):
+    """Three consecutive steps of a session whose GEMV images stay on the
+    device equal, bit for bit, the same steps with the images built on
+    the host each step: writing ``xq`` and ``sx`` into the device images
+    leaves nothing stale."""
+    h = np.random.default_rng(4).standard_normal((3, SH.d)).astype(
+        np.float32)
+    seen = []
+
+    def on_tile(kind, program, image, result):
+        if kind.startswith("gemv"):
+            seen.append((image, result.memory))
+
+    outs = []
+    for dec in (qwen2.Qwen2Decoder(SH, params, sched, on_tile=on_tile),
+                _HostImages(SH, params, sched)):
+        sess = dec.new_session()
+        outs.append([dec.step(sess, h[i]) for i in range(3)])
+    for a, b in zip(*outs):
+        for key in ("layers", "k", "v"):
+            for x, y in zip(getattr(a, key), getattr(b, key)):
+                np.testing.assert_array_equal(x, y)
+        np.testing.assert_array_equal(a.final, b.final)
+        assert len(a.quant) == len(b.quant) == LAYERS
+        for qa, qb in zip(a.quant, b.quant):
+            assert sorted(qa) == sorted(qb)
+            for name in qa:
+                for x, y in zip(qa[name], qb[name]):
+                    np.testing.assert_array_equal(x, y)
+    # every GEMV tile of the resident steps: 3 steps x 2 layers x 47
+    assert len(seen) == 3 * LAYERS * sum(g[2] for g in SH.grid().values())
+    assert all(isinstance(img, jax.Array) and isinstance(mem, jax.Array)
+               for img, mem in seen)
+
+
+def test_step_after_warm_up_compiles_nothing(params):
+    """Once every tile kind is promoted and its fused executables warmed
+    (as the benchmark's set-up does), the decoder's own warm-up leaves
+    nothing for a step to compile."""
+    compiles = []
+    armed = [False]
+
+    def listen(event, duration, **kw):
+        if armed[0] and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    s = MVEScheduler(MVEConfig(), background=True, promote_after=2,
+                     max_batch=2)
+    try:
+        images = qwen2.example_images(SH, seed=3)
+        for kind, t in qwen2.tiles(SH).items():
+            for _ in range(2):
+                s.submit(t.program, images[kind]).result(timeout=300)
+            fused = compile_program(t.program, s.cfg, mode="fused")
+            fused.run(images[kind])
+            fused.run_batch(np.stack([images[kind]] * 2))
+        dec = qwen2.Qwen2Decoder(SH, params, s)
+        sess = dec.new_session()
+        h = np.random.default_rng(5).standard_normal(SH.d).astype(
+            np.float32)
+        armed[0] = True
+        dec.step(sess, h)
+        armed[0] = False
+    finally:
+        s.close()
+    assert compiles == []
 
 
 def test_bf16_control_fails_tolerance(params):

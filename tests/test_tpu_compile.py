@@ -160,3 +160,49 @@ def test_qwen2_decode_tile_fused(one_chip, kind, batch):
         _on(one_chip, zeros.shape, zeros.dtype)).compile()
     assert compiled.as_text().startswith(f"HloModule jit__execute.dec_{kind}")
     assert compiled.out_info[0].shape == shape
+
+
+@pytest.mark.parametrize("stage,kind,n", [("gate_up", "gemv_d", 76),
+                                          ("down", "gemv_ff", 7)])
+def test_qwen2_resident_gemv_stage(one_chip, stage, kind, n):
+    """A GEMV stage's input update over a session's device images and
+    the gather of its answers' ``y`` (``Qwen2Decoder._gemv_stage``)."""
+    from repro.configs.qwen2_0_5b import CONFIG
+    from repro.nn import qwen2
+    sh = qwen2.Shapes.of(CONFIG, split=512, max_splits=16)
+    tile = qwen2.tiles(sh)[kind]
+    assert sum(sh.grid()[p][2] for p in qwen2.GEMV_STAGES[stage]) == n
+    k = sh.d if kind == "gemv_d" else sh.ff
+    images = (_on(one_chip, (tile.zeros.size,), jnp.float32),) * n
+    update = tile.write_input.lower(
+        images, _on(one_chip, (k,), jnp.float32),
+        _on(one_chip, (1,), jnp.float32)).compile()
+    assert [o.shape for o in update.out_info] == [(tile.zeros.size,)] * n
+    gather = tile.gather_y.lower(images).compile()
+    assert gather.out_info.shape == (n * sh.mt,)
+
+
+def test_qwen2_gemv_device_batch_stack_and_split(one_chip):
+    """The device stack of 16 ``gemv_d`` images, the split of the batch's
+    memories into per-request arrays, and the read of one request's
+    registers and Tag."""
+    from repro.configs.qwen2_0_5b import CONFIG
+    from repro.core import engine
+    from repro.nn import qwen2
+    sh = qwen2.Shapes.of(CONFIG, split=512, max_splits=16)
+    tile = qwen2.tiles(sh)["gemv_d"]
+    size = tile.zeros.size
+    stack = engine._stack_images.lower(
+        (_on(one_chip, (size,), jnp.float32),) * 16).compile()
+    assert stack.out_info.shape == (16, size)
+    split = engine.split_rows.lower(
+        _on(one_chip, (16, size), jnp.float32)).compile()
+    assert [o.shape for o in split.out_info] == [(size,)] * 16
+    cp = engine.compile_program(tile.program, mode="fused")
+    masks, zeros = cp._fused_operands()
+    _, regs, tag, _ = jax.eval_shape(
+        jax.vmap(cp._fused_fn, in_axes=(0, None, None)),
+        jax.ShapeDtypeStruct((16, size), jnp.float32), masks, zeros)
+    row = engine.take_row.lower(_tree_on(one_chip, (dict(regs), tag)),
+                                _on(one_chip, (), jnp.int32)).compile()
+    assert row.out_info[1].shape == tag.shape[1:]
