@@ -1,5 +1,5 @@
 """Benchmark runner: one section per paper table/figure + kernel bench +
-the per-target sweep + the roofline table from the dry-run artifacts.
+the per-target sweep.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig7,fig10]
     PYTHONPATH=src python -m benchmarks.run --only engine --json BENCH_engine.json
@@ -26,7 +26,6 @@ from .kernels_bench import kernel_microbench
 from .models_bench import models_bench
 from .opt_bench import opt_report
 from .resilience_bench import resilience_report, resilience_report_quick
-from .roofline import roofline_rows
 from .serving_bench import mve_serving, mve_serving_quick, serving_throughput
 from .silicon_bench import silicon_report, silicon_report_quick
 from .targets_bench import target_sweep
@@ -52,7 +51,6 @@ SECTIONS = {
     "serving": mve_serving,
     "serving_lm": serving_throughput,
     "resilience": resilience_report,
-    "roofline": roofline_rows,
     "silicon": silicon_report,
 }
 

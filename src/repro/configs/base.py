@@ -66,7 +66,7 @@ class ModelConfig:
     # transients (SP all-gathers, saved carries, CE chunks) scale with the
     # microbatch, so this is the production memory knob for big train cells.
     grad_accum: int = 1
-    # beyond-paper serving/training knobs (see EXPERIMENTS.md §Perf)
+    # beyond-paper serving/training knobs
     kv_cache_dtype: str = "bfloat16"    # bfloat16 | float8
     grad_accum_dtype: str = "float32"   # float32 | bfloat16
 
@@ -171,8 +171,7 @@ SHAPES = {
 def cell_supported(cfg: ModelConfig, cell: ShapeCell) -> Tuple[bool, str]:
     """long_500k needs sub-quadratic sequence mixing: SSM/hybrid only.
 
-    (All archs here are decoder-capable, so decode_32k always applies; see
-    DESIGN.md §Arch-applicability for the skip rationale.)
+    (All archs here are decoder-capable, so decode_32k always applies.)
     """
     if cell.name == "long_500k" and cfg.family not in ("ssm", "hybrid"):
         return False, ("pure full-attention architecture: a 512K KV-cache "

@@ -196,7 +196,9 @@ class MVEProgramServer:
     ``benchmarks/serving_bench.py`` to replay the Table III workload mix.
 
     Thread-safe like the scheduler it wraps; ``keep_done`` bounds the
-    finished-request history a long-lived server retains.
+    finished-request history a long-lived server retains.  Without a
+    ``scheduler``, one is built from ``scheduler_kwargs``, which are
+    :class:`~repro.runtime.scheduler.MVEScheduler`'s options.
     """
 
     def __init__(self, scheduler=None, keep_done: int = 4096,

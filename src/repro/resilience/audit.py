@@ -5,16 +5,14 @@ answer.  The only defense is re-computation on an independent path and a
 bit-exact compare, which is exactly the repo's conformance contract
 (``tests/test_conformance.py``: interp == fused == VM, bit for bit).
 :class:`ResultAuditor` packages that contract as a runtime component the
-scheduler samples per served request:
-
-* ``method="cross"`` — re-execute on the *other* executor (a VM-served
-  result is checked against the fused engine and vice versa).  Cheap
-  (one extra warm dispatch) and catches any single-executor corruption,
-  because the two executors share no datapath code past the program
-  walk.
-* ``method="oracle"`` — re-execute on the stepwise interpreter, the
-  semantic ground truth.  Orders of magnitude slower; for forensic runs
-  and low sample rates.
+scheduler samples per served request: it re-executes on the *other*
+executor (a VM-served result is checked against the fused engine and
+vice versa).  That is cheap (one extra warm dispatch) and catches any
+single-executor corruption, because the two executors share no datapath
+code past the program walk.  Where there is no other executor (an
+oracle-served result, or a program whose other mode falls back to the
+served one) the reference is the stepwise interpreter, the semantic
+ground truth.
 
 On mismatch the auditor returns the reference payload — the scheduler
 serves *that*, counts ``audit_corrected``, and records a breaker failure
@@ -25,7 +23,7 @@ audits the same requests, independent of retry interleaving.
 """
 from __future__ import annotations
 
-import threading
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -36,18 +34,15 @@ AuditReference = Tuple[np.ndarray, Dict[int, np.ndarray], np.ndarray]
 
 
 class ResultAuditor:
-    def __init__(self, rate: float = 1.0, seed: int = 0,
-                 method: str = "cross", injector=None):
-        if method not in ("cross", "oracle"):
-            raise ValueError(f"unknown audit method {method!r}")
+    """Audits served results at ``rate``; ``oracle(cfg)`` gives the
+    stepwise interpreter of a machine config (the scheduler's own)."""
+
+    def __init__(self, oracle, rate: float = 1.0, seed: int = 0,
+                 injector=None):
+        self.oracle = oracle
         self.rate = float(rate)
         self.seed = seed
-        self.method = method
         self.injector = injector        # suspended during reference runs
-        self._lock = threading.Lock()
-        self.checked = 0
-        self.mismatches = 0
-        self._oracles: Dict[object, object] = {}
 
     def should_audit(self, rid: int) -> bool:
         if self.rate <= 0.0:
@@ -62,52 +57,23 @@ class ResultAuditor:
         """Bit-compare a served result against an independent
         re-execution; returns ``None`` when it verifies, else the
         reference ``(memory, regs, tag)`` to serve instead."""
-        ref = self._reference(program, memory_in, cfg, served_mode)
-        mem, regs, tag = ref
-        with self._lock:
-            self.checked += 1
+        program = list(program)
+        with self.injector.suspended() if self.injector is not None \
+                else contextlib.nullcontext():
+            state = None
+            if served_mode in ("vm", "fused"):
+                from ..core.engine import compile_program
+                other = "fused" if served_mode == "vm" else "vm"
+                cp = compile_program(program, cfg, mode=other)
+                if cp.mode != served_mode:  # no silent same-path "audit"
+                    mem, state = cp.run(memory_in)
+            if state is None:
+                # an oracle-served result, or one whose other mode fell
+                # back to the served one: stepwise ground truth
+                mem, state = self.oracle(cfg).run_stepwise(program,
+                                                           memory_in)
+        mem, tag = np.asarray(mem), np.asarray(state.tag)
         if np.array_equal(mem, to_host(served_memory)) and \
                 np.array_equal(tag, to_host(served_tag)):
             return None
-        with self._lock:
-            self.mismatches += 1
-        return ref
-
-    def _reference(self, program, memory_in, cfg,
-                   served_mode: str) -> AuditReference:
-        if self.injector is not None:
-            with self.injector.suspended():
-                return self._reference_unshielded(
-                    program, memory_in, cfg, served_mode)
-        return self._reference_unshielded(program, memory_in, cfg,
-                                          served_mode)
-
-    def _reference_unshielded(self, program, memory_in, cfg,
-                              served_mode: str) -> AuditReference:
-        program = list(program)
-        if self.method == "cross" and served_mode in ("vm", "fused"):
-            from ..core.engine import compile_program
-            other = "fused" if served_mode == "vm" else "vm"
-            cp = compile_program(program, cfg, mode=other)
-            if cp.mode != served_mode:      # no silent same-path "audit"
-                mem, state = cp.run(memory_in)
-                return (np.asarray(mem),
-                        {r: np.asarray(v) for r, v in state.regs.items()},
-                        np.asarray(state.tag))
-        # oracle method, an oracle-served result, or a cross request whose
-        # other mode fell back to the served one: stepwise ground truth.
-        mem_i, st_i = self._oracle(cfg).run_stepwise(program, memory_in)
-        return (np.asarray(mem_i),
-                {r: np.asarray(v) for r, v in st_i.regs.items()},
-                np.asarray(st_i.tag))
-
-    def _oracle(self, cfg):
-        o = self._oracles.get(cfg)
-        if o is None:
-            from ..core.interp import MVEInterpreter
-            o = self._oracles[cfg] = MVEInterpreter(cfg, compiled=False)
-        return o
-
-    def counters(self) -> Dict[str, int]:
-        with self._lock:
-            return {"checked": self.checked, "mismatches": self.mismatches}
+        return mem, {r: np.asarray(v) for r, v in state.regs.items()}, tag

@@ -168,8 +168,9 @@ class FaultInjector:
     known-good resource, not the faulty one).  Sticky specs
     (``times=-1``) are the exception a test opts into via rid binding:
     suspension still wins, so permanently poisoned requests are modeled
-    by *not* suspending the single-request retry path for dispatch
-    faults (see ``MVEScheduler._run_single``).
+    by *not* suspending a recovery run's scheduler-level ``dispatch``
+    site, which matches them with ``shielded=True`` (see
+    ``MVEScheduler._dispatch``).
     """
 
     def __init__(self, plan: FaultPlan,

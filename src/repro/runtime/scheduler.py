@@ -25,13 +25,16 @@ Scheduling policy (docs/SERVING.md has the design note):
   zero per-program XLA compiles, which is what keeps a stream of
   data-dependent programs, e.g. one spmm program per sparsity pattern,
   servable at all), and a program whose submission count reaches
-  ``promote_after`` is **promoted to the fused tier**, whose per-program
-  batched executable is ~an order of magnitude faster per image on the
-  measured CPU substrate (``BENCH_engine.json`` ``serving`` section).
-  ``promote_after=None`` disables promotion (pure-VM scheduler).
+  ``promote_after`` is **promoted to the fused tier**, its own
+  per-program batched executable.  ``promote_after=None`` disables
+  promotion (pure-VM scheduler).
 * All group dispatches of a drain cycle are enqueued asynchronously
   (``run_batch_async`` / ``run_async``); the scheduler syncs once per
   cycle, not once per request.
+* Every served request, batched or alone, first try or retry, ends in
+  one answer step (:meth:`MVEScheduler._answer`): injected finalize
+  faults, the sampled audit, device arrays for a device image's answer,
+  and the :class:`ServeResult`.
 
 Targets: ``submit(..., target="rvv-1d")`` accepts any registered
 :mod:`repro.targets` target — requests bucket per target (compilations
@@ -48,50 +51,16 @@ forms batches with a ``max_batch``/``max_wait_ms`` window policy, and
 :meth:`submit` returns tickets that resolve concurrently.
 
 Failure semantics (docs/SERVING.md "Failure semantics"; the components
-live in :mod:`repro.resilience`):
-
-* **Every submit() resolves** — with a result or a typed
-  :class:`~repro.resilience.errors.SchedulerError`.  No orphaned
-  tickets: a timed-out waiter can :meth:`Ticket.cancel`, :meth:`close`
-  resolves everything still pending with ``SchedulerClosedError``, and
-  the serve cycle carries a backstop that resolves any ticket an
-  internal error would otherwise drop.
-* **Bounded retry + per-request deadlines** — transient dispatch
-  failures replay with exponential backoff
-  (:class:`~repro.resilience.breaker.RetryPolicy`); a request past its
-  deadline resolves with ``DeadlineExceededError`` instead of retrying
-  forever.
-* **Batch bisection** — one poisoned request must not fail its vmapped
-  batch: a failed group dispatch is split in half and re-dispatched
-  until the poison is isolated; clean halves still serve *batched*, the
-  poisoned request is retried alone, then **quarantined** (resolved with
-  ``QuarantinedError``; re-submissions are rejected until a cooldown
-  expires).
-* **Circuit breakers + tier degradation** — failures are recorded per
-  ``(signature bucket, target, tier)``; an open breaker demotes traffic
-  down the ladder *fused → VM → stepwise oracle* (the oracle is pure
-  Python: slow, but it cannot share a failure mode with the jitted
-  executors), with half-open probes re-admitting a recovered tier.
-* **Bounded admission queue** — ``max_queue`` + ``admission="block"``
-  (backpressure the submitter) or ``"shed"`` (resolve immediately with
-  ``QueueFullError``).
-* **Supervised worker** — the background thread heartbeats a
-  :class:`~repro.runtime.health.HeartbeatMonitor`; if it dies
-  mid-stream, in-hand tickets are re-queued and a supervisor restarts
-  the thread, so a worker death is invisible to clients (chaos-tested
-  with injected thread deaths).
-* **Sampled integrity audit** — optional bit-exact re-execution of
-  served results on an independent executor
-  (:class:`~repro.resilience.audit.ResultAuditor`) catches silent
-  corruption (the SRAM bit-flip model); corrupted results are replaced
-  by the verified reference and the corrupting tier accumulates breaker
-  failures.
-
-Fault injection: pass ``injector=FaultInjector(plan)`` to run a
-deterministic chaos schedule against the real scheduler paths —
-``benchmarks/resilience_bench.py`` measures throughput under 0/1/10 %
-injected fault rates and ``tests/test_resilience.py`` replays a seeded
-10 % chaos stream and asserts full recovery.
+live in :mod:`repro.resilience`): every ``submit()`` resolves, with a
+result or a typed :class:`~repro.resilience.errors.SchedulerError`.  A
+failed batch is bisected until the poisoned request is isolated; that
+request is retried with backoff down the ladder *fused → VM → stepwise
+oracle*, under per-tier circuit breakers, and quarantined when every
+tier fails.  Per-request deadlines, a bounded admission queue, a
+supervised background worker and a sampled bit-exact audit
+(:class:`~repro.resilience.audit.ResultAuditor`) complete it.  Pass
+``injector=FaultInjector(plan)`` to run a deterministic chaos schedule
+against these paths (``tests/test_resilience.py``).
 
 Device images: a request whose memory image is a ``jax.Array`` is
 stacked, run and answered on the device, its answer's memory, registers
@@ -104,12 +73,13 @@ fuzzes that equivalence across all four executors.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import threading
 import time
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -370,10 +340,24 @@ class SchedulerStats:
 class _DispatchCtx:
     """Everything the recovery path needs to replay a group dispatch."""
 
-    prog: tuple
     key: tuple                   # target-tagged signature bucket
-    fused: Optional[CompiledProgram]
-    routed_vm: bool
+    runner: CompiledProgram      # the group's tier: fused or its base tier
+
+    @property
+    def bkey(self) -> tuple:
+        """The circuit-breaker key of the group's tier."""
+        return (self.key, self.runner.mode)
+
+    @property
+    def routed_vm(self) -> bool:
+        """Whether the group routes to the VM (after the target tag)."""
+        return self.key[1] == "vm"
+
+    @property
+    def resident(self) -> bool:
+        """Whether the group's images are device arrays: the bucket's last
+        element (``batch_group_key``), so a group never mixes the two."""
+        return self.key[-1]
 
 
 class MVEScheduler:
@@ -383,7 +367,6 @@ class MVEScheduler:
     Parameters
     ----------
     cfg: machine config shared by every request (one lane geometry).
-    mode: executor for the base tier (engine default: ``"vm"``).
     max_batch: largest fused-tier dispatch; groups beyond it are split.
     vm_max_batch: largest VM-tier dispatch.  The vmapped while-loop
         datapath stops gaining past small batches on the CPU substrate
@@ -397,39 +380,28 @@ class MVEScheduler:
         drainer, i.e. ``background=True`` or another thread calling
         :meth:`drain`) or ``"shed"`` (resolve immediately with
         ``QueueFullError``).
-    default_deadline_s: deadline applied to submissions that do not pass
-        their own (``None`` = no deadline).
-    retry: :class:`~repro.resilience.breaker.RetryPolicy` for failed
-        single-request re-executions.
     breaker: :class:`~repro.resilience.breaker.CircuitBreaker` keyed per
         ``(signature bucket, tier)``; open tiers are skipped (degradation
         ladder fused → vm → oracle).
     quarantine_cooldown_s: how long a poisoned program key is rejected
         before one probe submission is allowed again.
-    audit_rate / audit_method / audit_seed: sampled integrity audit of
-        served results (:class:`~repro.resilience.audit.ResultAuditor`);
-        rate 0 disables.
+    audit_rate: share of served results re-executed on the other
+        executor and compared bit for bit
+        (:class:`~repro.resilience.audit.ResultAuditor`); 0 disables.
     injector: :class:`~repro.resilience.faults.FaultInjector` executing a
         deterministic chaos plan against this scheduler's serve paths.
     """
 
-    def __init__(self, cfg: Optional[MVEConfig] = None,
-                 mode: Optional[str] = None, max_batch: int = 16,
+    def __init__(self, cfg: Optional[MVEConfig] = None, max_batch: int = 16,
                  vm_max_batch: int = 4,
                  promote_after: Optional[int] = 2,
                  background: bool = False, max_wait_ms: float = 2.0,
                  max_queue: Optional[int] = None,
                  admission: str = "block",
-                 default_deadline_s: Optional[float] = None,
-                 retry: Optional[RetryPolicy] = None,
                  breaker: Optional[CircuitBreaker] = None,
                  quarantine_cooldown_s: float = 30.0,
-                 audit_rate: float = 0.0,
-                 audit_method: str = "cross",
-                 audit_seed: int = 0,
-                 injector=None):
+                 audit_rate: float = 0.0, injector=None):
         self.cfg = cfg or MVEConfig()
-        self.mode = mode
         # Batch caps are floored to powers of two: dispatch stacks are
         # padded to the next power of two, so a non-pow2 cap would let a
         # padded dispatch exceed it.
@@ -441,8 +413,7 @@ class MVEScheduler:
             raise ValueError(f"unknown admission policy {admission!r}")
         self.max_queue = max_queue
         self.admission = admission
-        self.default_deadline_s = default_deadline_s
-        self.retry = retry or RetryPolicy()
+        self.retry = RetryPolicy()
         self.quarantine_cooldown_s = quarantine_cooldown_s
         self.stats = SchedulerStats()
         # Threshold > (1 + default max_retries): one permanently poisoned
@@ -451,9 +422,9 @@ class MVEScheduler:
         self._breaker = breaker or CircuitBreaker(threshold=5,
                                                   cooldown_s=5.0)
         self._injector = injector
-        self._auditor = ResultAuditor(
-            rate=audit_rate, seed=audit_seed, method=audit_method,
-            injector=injector) if audit_rate > 0.0 else None
+        self._auditor = ResultAuditor(self._oracle, audit_rate,
+                                      injector=injector) \
+            if audit_rate > 0.0 else None
         self._heartbeat = HeartbeatMonitor(hosts=[], timeout_s=10.0)
         self._stragglers = StragglerDetector(window=8)
         self._sleep = time.sleep           # patchable in tests
@@ -521,9 +492,8 @@ class MVEScheduler:
         targets.
 
         ``deadline_s`` bounds this request's submit-to-resolution time
-        (default: the scheduler's ``default_deadline_s``); past it the
-        ticket resolves with ``DeadlineExceededError`` instead of
-        retrying further.
+        (default: none); past it the ticket resolves with
+        ``DeadlineExceededError`` instead of retrying further.
 
         The returned ticket **always resolves** — with a
         :class:`ServeResult` or a typed
@@ -542,10 +512,7 @@ class MVEScheduler:
             program = kernel.program
         elif memory is None:
             raise TypeError("raw program submissions need a memory image")
-        cp = compile_program(kernel or program, cfg, mode=self.mode,
-                             cache_tag=tag)
-        if deadline_s is None:
-            deadline_s = self.default_deadline_s
+        cp = compile_program(kernel or program, cfg, cache_tag=tag)
         t = Ticket(next(self._rid), tuple(program), memory, cp,
                    submitted_at=submitted_at, kernel=kernel,
                    cfg=cfg, target=tag,
@@ -578,10 +545,6 @@ class MVEScheduler:
             self._pending.append(t)
             self._wake.notify()
         return t
-
-    def submit_many(self, requests: Sequence[Tuple[isa.Program, object]]
-                    ) -> List[Ticket]:
-        return [self.submit(p, m) for p, m in requests]
 
     def drain(self) -> None:
         """Serve every pending request on the calling thread and return
@@ -683,7 +646,8 @@ class MVEScheduler:
                 "sheds": st.sheds,
                 "promotion_failures": st.promotion_failures,
             },
-            "audit": (self._auditor.counters()
+            "audit": ({"checked": st.audit_checked,
+                       "mismatches": st.audit_corrected}
                       if self._auditor is not None else None),
             "injected": (self._injector.counts()
                          if self._injector is not None else None),
@@ -813,16 +777,11 @@ class MVEScheduler:
         with span(GROUP):
             buckets = self._group(batch)
 
-        inflight = []   # (ctx, tickets, tier, finalize_thunk)
+        inflight = []   # (ctx, tickets, finalize_thunk)
         for key, groups in buckets.items():
             # Same signature bucket back to back: every VM group replays
             # through the same signature-keyed executable while it is hot.
-            # Only VM-routed requests (key[1], after the target tag) get
-            # the VM-tier batch cap; fused-routed ones
-            # (non-float32-canonical images, VM fallbacks) batch at the
-            # full fused cap.
-            routed_vm = key[1] == "vm"
-            for (prog, _), tickets in groups.items():
+            for tickets in groups.values():
                 with span(GROUP):
                     tickets = self._admit(tickets)
                     fused, due = self._promotion(key, tickets[0]) \
@@ -831,47 +790,41 @@ class MVEScheduler:
                     continue
                 if due:
                     fused = self._promote(key, tickets[0])
-                ctx = _DispatchCtx(prog=prog, key=key, fused=fused,
-                                   routed_vm=routed_vm)
-                btier = "fused" if fused is not None else tickets[0].cp.mode
-                if not self._breaker.allow((key, btier)):
+                ctx = _DispatchCtx(key=key, runner=fused
+                                   if fused is not None else tickets[0].cp)
+                if not self._breaker.allow(ctx.bkey):
                     # Tier breaker open: skip the batched dispatch and
                     # walk each request down the degradation ladder.
                     self.stats.breaker_skips += 1
                     for t in tickets:
                         self._serve_one_resilient(ctx, t, None)
                     continue
-                cap = self.vm_max_batch if routed_vm and fused is None \
+                # Only VM-routed requests on their base tier get the VM
+                # tier's batch cap; fused-routed ones (non-float32-
+                # canonical images, VM fallbacks) batch at the fused cap.
+                cap = self.vm_max_batch if ctx.routed_vm and fused is None \
                     else self.max_batch
                 for chunk in _chunks(tickets, cap):
                     try:
-                        inflight.append(
-                            (ctx,) + self._dispatch(prog, chunk, fused,
-                                                    routed_vm))
-                    except (KeyboardInterrupt, SystemExit):
-                        raise
+                        inflight.append((ctx, chunk, self._dispatch(
+                            ctx, chunk, ctx.runner)))
                     except Exception as e:
-                        self._breaker.record_failure((key, btier)) and \
-                            self._note_open()
+                        self._record_failure(ctx.bkey)
                         self._recover_group(ctx, chunk, e)
 
-        for ctx, tickets, tier, finalize in inflight:
-            btier = "fused" if ctx.fused is not None \
-                else tickets[0].cp.mode
+        for ctx, tickets, finalize in inflight:
             t0 = time.perf_counter()
             try:
                 results = finalize()
-            except (KeyboardInterrupt, SystemExit):
-                raise
             except Exception as e:
-                self._breaker.record_failure((ctx.key, btier)) and \
-                    self._note_open()
+                self._record_failure(ctx.bkey)
                 self._recover_group(
                     ctx, [t for t in tickets if not t.done()], e)
                 continue
             with span(RESOLVE):
-                self._stragglers.record(btier, time.perf_counter() - t0)
-                self._breaker.record_success((ctx.key, btier))
+                self._stragglers.record(ctx.runner.mode,
+                                        time.perf_counter() - t0)
+                self._breaker.record_success(ctx.bkey)
                 for t, r in zip(tickets, results):
                     t._resolve(result=r)
 
@@ -958,12 +911,9 @@ class MVEScheduler:
                     self._injector.compile([ticket.rid], tier="fused")
                 hot = compile_program(list(pk[0]), ticket.cfg, mode="fused",
                                       cache_tag=ticket.target)
-        except (KeyboardInterrupt, SystemExit):
-            raise
         except Exception:
             self.stats.promotion_failures += 1
-            self._breaker.record_failure((key, "fused")) and \
-                self._note_open()
+            self._record_failure((key, "fused"))
             return None
         self._promoted[pk] = hot
         while len(self._promoted) > _PROMOTED_CAP:
@@ -971,9 +921,11 @@ class MVEScheduler:
         self.stats.promotions += 1
         return hot
 
-    def _note_open(self) -> bool:
-        self.stats.breaker_opens += 1
-        return True
+    def _record_failure(self, bkey) -> bool:
+        """A failure on a tier's breaker; whether it opened the breaker."""
+        opened = self._breaker.record_failure(bkey)
+        self.stats.breaker_opens += opened
+        return opened
 
     # -- recovery ladder ---------------------------------------------------
     def _recover_group(self, ctx: _DispatchCtx, tickets: List[Ticket],
@@ -992,11 +944,8 @@ class MVEScheduler:
         mid = len(tickets) // 2
         for half in (tickets[:mid], tickets[mid:]):
             try:
-                _, tier, fin = self._dispatch(ctx.prog, half, ctx.fused,
-                                              ctx.routed_vm, shielded=True)
-                results = fin()
-            except (KeyboardInterrupt, SystemExit):
-                raise
+                results = self._dispatch(ctx, half, ctx.runner,
+                                         shielded=True)()
             except Exception as e:
                 self._recover_group(ctx, half, e)
                 continue
@@ -1014,13 +963,11 @@ class MVEScheduler:
             return
         last = first_err
         attempts = 0
-        ladder: List[Tuple[str, Optional[CompiledProgram]]] = []
-        if ctx.fused is not None:
-            ladder.append(("fused", ctx.fused))
-        if t.cp.mode not in [name for name, _ in ladder]:
-            ladder.append((t.cp.mode, t.cp))
-        ladder.append(("oracle", None))
-        for tier, runner in ladder:
+        # the group's tier, the base tier when that is another, the oracle
+        ladder = [ctx.runner] + ([t.cp] if t.cp.mode != ctx.runner.mode
+                                 else []) + [None]
+        for runner in ladder:
+            tier = runner.mode if runner is not None else "oracle"
             bkey = (ctx.key, tier)
             if tier != "oracle" and not self._breaker.allow(bkey):
                 self.stats.breaker_skips += 1
@@ -1042,15 +989,15 @@ class MVEScheduler:
                 if attempts > 1 or first_err is not None:
                     self.stats.retries += 1
                 try:
-                    r = self._run_single(ctx, t, tier, runner)
-                except (KeyboardInterrupt, SystemExit):
-                    raise
+                    # Shielded: one-shot injected faults do not re-fire (a
+                    # retry runs on a fresh resource), but rid-bound
+                    # sticky faults (a poisoned request) still do.
+                    r = self._dispatch(ctx, [t], runner, shielded=True)()[0] \
+                        if runner is not None else self._oracle_answer(ctx, t)
                 except Exception as e:
                     last = e
-                    if tier != "oracle":
-                        if self._breaker.record_failure(bkey):
-                            self._note_open()
-                            break           # tier just opened: demote now
+                    if tier != "oracle" and self._record_failure(bkey):
+                        break               # tier just opened: demote now
                     continue
                 if tier != "oracle":
                     self._breaker.record_success(bkey)
@@ -1062,63 +1009,21 @@ class MVEScheduler:
         # Every tier (oracle included) failed: isolate the poison.
         self._quarantine_request(ctx, t, last, attempts)
 
-    def _run_single(self, ctx: _DispatchCtx, t: Ticket, tier: str,
-                    runner: Optional[CompiledProgram]) -> ServeResult:
-        """One shielded single-request execution on a given tier.
-
-        Shielded = one-shot injected faults do not re-fire (a retry runs
-        on a fresh resource), but rid-bound *sticky* faults — the model
-        of a permanently poisoned request — still do."""
-        inj = self._injector
-        if inj is not None:
-            inj.dispatch([t.rid], tier, shielded=True)
+    def _oracle_answer(self, ctx: _DispatchCtx, t: Ticket) -> ServeResult:
+        """Serve one request on the stepwise oracle, shielded."""
+        if self._injector is not None:
+            self._injector.dispatch([t.rid], "oracle", shielded=True)
         self.stats.dispatches += 1
         self.stats.singles += 1
         self.stats.max_batch_seen = max(self.stats.max_batch_seen, 1)
-        resident = isinstance(t.memory, jax.Array)
-        if tier == "oracle":
-            self.stats.oracle_serves += 1
-            mem_i, st_i = self._oracle(t.cfg).run_stepwise(
-                list(t.program), t.memory)
-            if resident:
-                mem, regs, tag = self._device_answer(mem_i, st_i.regs,
-                                                     st_i.tag)
-                return ServeResult(memory=mem, regs=regs, tag=tag,
-                                   batch_size=1, tier="oracle",
-                                   trace=st_i.trace, kernel=t.kernel)
-            return ServeResult(
-                memory=np.asarray(mem_i),
-                regs={r: np.asarray(v) for r, v in st_i.regs.items()},
-                tag=np.asarray(st_i.tag), batch_size=1, tier="oracle",
-                trace=st_i.trace, kernel=t.kernel)
-        if inj is not None:
-            with inj.suspended():           # recovery path is shielded
-                mem_j, state = runner.run(t.memory)
-        else:
-            mem_j, state = runner.run(t.memory)
-        mem = mem_j if resident else to_host(mem_j)
-        regs, tag = state.regs, state.tag
-        if self._auditor is not None and self._auditor.should_audit(t.rid):
-            self.stats.audit_checked += 1
-            ref = self._auditor.check(t.program, t.memory, t.cfg, mem,
-                                      tag, runner.mode)
-            if ref is not None:
-                self.stats.audit_corrected += 1
-                self._breaker.record_failure((ctx.key, tier)) and \
-                    self._note_open()
-                mem, regs, tag = ref
-        if resident:
-            mem, regs, tag = self._device_answer(mem, regs, tag)
-        return ServeResult(memory=mem, regs=regs, tag=tag, batch_size=1,
-                           tier=runner.mode, trace=state.trace,
-                           kernel=t.kernel)
-
-    def _device_answer(self, mem, regs, tag):
-        """A device request's answer as device arrays; one that never
-        left the device counts in ``resident_requests``."""
-        mem, regs, tag, stayed = _device_arrays(mem, regs, tag)
-        self.stats.resident_requests += stayed
-        return mem, regs, tag
+        self.stats.oracle_serves += 1
+        mem, st = self._oracle(t.cfg).run_stepwise(list(t.program), t.memory)
+        regs, tag = st.regs, st.tag
+        if not ctx.resident:
+            mem, tag = np.asarray(mem), np.asarray(tag)
+            regs = {r: np.asarray(v) for r, v in regs.items()}
+        return self._answer(ctx, [t], "oracle", None, (mem, regs, tag),
+                            st.trace, shielded=True)[0]
 
     def _oracle(self, cfg: MVEConfig):
         o = self._oracles.get(cfg)
@@ -1158,141 +1063,141 @@ class MVEScheduler:
         t._resolve(error=err)
 
     # -- dispatch ----------------------------------------------------------
-    def _dispatch(self, prog: tuple, tickets: List[Ticket], fused,
-                  routed_vm: bool = True, shielded: bool = False):
-        """Launch one group asynchronously; returns a finalize thunk."""
+    def _dispatch(self, ctx: _DispatchCtx, tickets: List[Ticket],
+                  runner: CompiledProgram, shielded: bool = False):
+        """Launch one group on ``runner`` asynchronously; returns a
+        finalize thunk that answers it (:meth:`_answer`).  A shielded
+        dispatch is a recovery run: the engine's deep fault hooks are
+        suspended while it runs and finalizes."""
         n = len(tickets)
-        cp = tickets[0].cp
-        runner = fused if fused is not None else cp
-        btier = "fused" if fused is not None else cp.mode
         if n == 1:
             tier, rows = runner.mode, 1
         else:
-            tier = "vm" if fused is None and routed_vm else "fused"
+            tier = runner.mode if ctx.routed_vm else "fused"
             # Pad the stack to a power of two so each program compiles at
             # most log2(max_batch) batched executables; padded rows replay
             # the first request's image and are dropped after the dispatch.
             rows = next_pow2(n)
         inj = self._injector
-        rids = [t.rid for t in tickets]
-        auditor = self._auditor
+        shield = inj.suspended() if shielded and inj is not None \
+            else contextlib.nullcontext()
+        resident = ctx.resident
         stats = self.stats
-        # one group holds device images only or host images only
-        # (``batch_group_key``)
-        resident = isinstance(tickets[0].memory, jax.Array)
         with span(DISPATCH, tier=tier, n=n, rows=rows):
             if inj is not None:
-                inj.dispatch(rids, btier, shielded=shielded)
-            if n == 1:
-                # Singleton: skip the vmap wrapper (and get the exact
-                # random-access trace for free via finalize_run).
-                pending = runner.run_async(tickets[0].memory)
-                stats.singles += 1
-            else:
-                mems = [t.memory if resident else np.asarray(t.memory)
-                        for t in tickets]
-                mems += [mems[0]] * (rows - n)
-                # the engine stacks a list of device images on the device
-                pending = runner.run_batch_async(
-                    mems if resident else np.stack(mems))
-                stats.batched_requests += n
-                if tier == "fused":
-                    stats.fused_batches += 1
+                inj.dispatch([t.rid for t in tickets], runner.mode,
+                             shielded=shielded)
+            with shield:
+                if n == 1:
+                    # Singleton: skip the vmap wrapper (and get the exact
+                    # random-access trace for free via finalize_run).
+                    pending = runner.run_async(tickets[0].memory)
+                    stats.singles += 1
                 else:
-                    stats.vm_batches += 1
+                    mems = [t.memory if resident else np.asarray(t.memory)
+                            for t in tickets]
+                    mems += [mems[0]] * (rows - n)
+                    # the engine stacks a list of device images on the
+                    # device
+                    pending = runner.run_batch_async(
+                        mems if resident else np.stack(mems))
+                    stats.batched_requests += n
+                    if tier == "fused":
+                        stats.fused_batches += 1
+                    else:
+                        stats.vm_batches += 1
             stats.dispatches += 1
             stats.max_batch_seen = max(stats.max_batch_seen, n)
 
-        def fin_single():
-            with span(SYNC):
-                runner.wait(pending)
-            with span(FETCH):
-                mem, state = runner.finalize_run(pending)
-                if not resident:
-                    mem = to_host(mem)
-            with span(RESOLVE):
-                t = tickets[0]
-                regs, tag = state.regs, state.tag
-                if inj is not None and not shielded:
-                    mem = inj.finalize(rids, btier, mem)
-                if auditor is not None and auditor.should_audit(t.rid):
-                    stats.audit_checked += 1
-                    ref = auditor.check(t.program, t.memory, t.cfg, mem,
-                                        tag, runner.mode)
-                    if ref is not None:
-                        stats.audit_corrected += 1
-                        self._breaker.record_failure(
-                            (ticket_key(t), btier)) and self._note_open()
-                        mem, regs, tag = ref
-                if resident:
-                    mem, regs, tag = self._device_answer(mem, regs, tag)
-                return [ServeResult(memory=mem, regs=regs, tag=tag,
-                                    batch_size=1, tier=runner.mode,
-                                    trace=state.trace, kernel=t.kernel)]
-
-        def fin_batch():
-            with span(SYNC):
-                runner.wait(pending)
-            with span(FETCH):
-                mem, regs, tag = runner.finalize_batch(pending)
-                if not resident:
-                    # One device->host transfer per array (not per
-                    # request): the per-request views below slice host
-                    # memory.
-                    mem = to_host(mem)
-                    tag = to_host(tag)
-                    regs = {r: to_host(v) for r, v in regs.items()}
-            with span(RESOLVE):
-                if inj is not None and not shielded:
-                    rows_of = {t.rid: b for b, t in enumerate(tickets)}
-                    mem = inj.finalize(rids, btier, mem, rows_of)
-                stayed = False
-                if resident:
-                    mem, regs, tag, stayed = _device_arrays(mem, regs, tag)
-                    # split when the answers are read, off this thread
-                    rows_out = DeviceRows(mem, regs, tag)
-
-                def trace_fn():
-                    # Deferred static_trace access too: unread traces
-                    # cost nothing on the dispatch hot path.
-                    return [dataclasses.replace(ev)
-                            for ev in cp.static_trace]
-
-                out = []
-                for b in range(n):
-                    t = tickets[b]
-                    if resident:
-                        res = ServeResult(
-                            memory=None, regs=None, tag=None, batch_size=n,
-                            tier=tier, trace_fn=trace_fn, kernel=t.kernel,
-                            rows=rows_out, row=b)
+        def finalize():
+            with shield:
+                with span(SYNC):
+                    runner.wait(pending)
+                with span(FETCH):
+                    if n == 1:
+                        mem, state = runner.finalize_run(pending)
+                        regs, tag, trace = state.regs, state.tag, state.trace
                     else:
-                        res = ServeResult(
-                            memory=mem[b],
-                            regs={r: v[b] for r, v in regs.items()},
-                            tag=tag[b], batch_size=n, tier=tier,
-                            trace_fn=trace_fn, kernel=t.kernel)
-                    kept = stayed
-                    if auditor is not None and auditor.should_audit(t.rid):
-                        stats.audit_checked += 1
-                        ref = auditor.check(t.program, t.memory, t.cfg,
-                                            res.memory, res.tag, runner.mode)
-                        if ref is not None:
-                            stats.audit_corrected += 1
-                            self._breaker.record_failure(
-                                (ticket_key(t), btier)) and self._note_open()
-                            rmem, rregs, rtag = ref
-                            if resident:
-                                rmem, rregs, rtag, kept = _device_arrays(
-                                    rmem, rregs, rtag)
-                            res = ServeResult(
-                                memory=rmem, regs=rregs, tag=rtag,
-                                batch_size=n, tier=tier, trace_fn=trace_fn,
-                                kernel=t.kernel)
-                    stats.resident_requests += kept
-                    out.append(res)
-                return out
-        return tickets, tier, fin_single if n == 1 else fin_batch
+                        mem, regs, tag = runner.finalize_batch(pending)
+                        trace = None
+                    if not resident:
+                        # One device->host transfer per array (not per
+                        # request): a batch's per-request views slice host
+                        # memory.
+                        mem = to_host(mem)
+                        if n > 1:
+                            tag = to_host(tag)
+                            regs = {r: to_host(v) for r, v in regs.items()}
+            with span(RESOLVE):
+                return self._answer(ctx, tickets, tier, runner.mode,
+                                    (mem, regs, tag), trace, shielded)
+        return finalize
+
+    def _answer(self, ctx: _DispatchCtx, tickets: List[Ticket], tier: str,
+                mode: Optional[str], answer, trace,
+                shielded: bool) -> List[ServeResult]:
+        """The one step every served request ends in, whatever its path:
+        the injector's finalize faults (not when ``shielded``), the
+        sampled audit of the answer as it came back, a device request's
+        answer as device arrays, and each ticket's :class:`ServeResult`.
+
+        ``answer`` is ``(memory, regs, tag)`` of one request or, for more
+        than one ticket, of their batch, stacked; ``mode`` is the
+        executor that ran it (``None`` for the stepwise oracle, whose
+        answers are not audited) and ``trace`` a lone request's trace.
+        A batch of device images is split when its answers are read, off
+        this thread (:class:`DeviceRows`)."""
+        mem, regs, tag = answer
+        n = len(tickets)
+        inj, auditor, stats = self._injector, self._auditor, self.stats
+        if inj is not None and not shielded:
+            mem = inj.finalize([t.rid for t in tickets], mode, mem,
+                               {t.rid: b for b, t in enumerate(tickets)})
+        fixes = {}      # row -> the audit's reference, served in its place
+        if auditor is not None and mode is not None:
+            for b, t in enumerate(tickets):
+                if not auditor.should_audit(t.rid):
+                    continue
+                stats.audit_checked += 1
+                ref = auditor.check(t.program, t.memory, t.cfg,
+                                    mem[b] if n > 1 else mem,
+                                    tag[b] if n > 1 else tag, mode)
+                if ref is not None:
+                    stats.audit_corrected += 1
+                    self._record_failure((ctx.key, mode))
+                    fixes[b] = ref
+        if n == 1 and fixes:
+            mem, regs, tag = fixes.pop(0)
+        stayed, rows, trace_fn = False, None, None
+        if ctx.resident:
+            mem, regs, tag, stayed = _device_arrays(mem, regs, tag)
+            if n > 1:
+                rows = DeviceRows(mem, regs, tag)
+        if n > 1:
+            cp = tickets[0].cp
+
+            def trace_fn():
+                # Deferred static_trace access too: unread traces cost
+                # nothing on the dispatch hot path.
+                return [dataclasses.replace(ev) for ev in cp.static_trace]
+        out = []
+        for b, t in enumerate(tickets):
+            kept = stayed
+            if b in fixes:
+                row = fixes[b]
+                if ctx.resident:
+                    *row, kept = _device_arrays(*row)
+            elif n == 1:
+                row = (mem, regs, tag)
+            elif rows is not None:
+                row = (None, None, None)         # read from ``rows``
+            else:
+                row = (mem[b], {r: v[b] for r, v in regs.items()}, tag[b])
+            stats.resident_requests += kept
+            out.append(ServeResult(*row, batch_size=n, tier=tier,
+                                   trace=trace, trace_fn=trace_fn,
+                                   kernel=t.kernel, rows=rows, row=b))
+        return out
 
 
 def _device_arrays(mem, regs, tag):
@@ -1305,11 +1210,6 @@ def _device_arrays(mem, regs, tag):
     def up(a):
         return a if isinstance(a, jax.Array) else to_device(a)
     return up(mem), {r: up(v) for r, v in regs.items()}, up(tag), False
-
-
-def ticket_key(t: Ticket) -> tuple:
-    """The target-tagged signature bucket a ticket groups under."""
-    return (t.target,) + tuple(t.cp.batch_group_key(t.memory))
 
 
 def _chunks(seq: List, n: int):

@@ -3,8 +3,9 @@
 (docs/SERVING.md "Device images").
 
 Each path of the scheduler (the fused tier alone and batched, the VM
-tier before promotion, the oracle rung of the degradation ladder, the
-fault injector and the auditor) serves the same requests twice, once as
+tier before promotion, a retry and a bisected batch after a failed
+dispatch, the oracle rung of the degradation ladder, the fault injector
+and the auditor) serves the same requests twice, once as
 numpy images and once as device images, and the answers must agree bit
 for bit: memory image, every register with its dtype, and the Tag.
 """
@@ -78,6 +79,10 @@ def _flip():
     return FaultSpec(site="finalize", kind="bitflip", rid=1, word=3, bit=30)
 
 
+def _fused_dispatch_error():
+    return FaultPlan([FaultSpec(site="dispatch", kind="error", tier="fused")])
+
+
 #: path -> (requests, scheduler settings, fault plan, answers that stay
 #: on the device)
 PATHS = {
@@ -93,6 +98,8 @@ PATHS = {
     "bitflip": (3, dict(promote_after=1), FaultPlan([_flip()]), 0),
     "bitflip_audited": (3, dict(promote_after=1, audit_rate=1.0),
                         FaultPlan([_flip()]), 0),
+    "retried": (1, dict(promote_after=1), _fused_dispatch_error(), 1),
+    "bisected": (3, dict(promote_after=1), _fused_dispatch_error(), 3),
 }
 
 
@@ -139,6 +146,14 @@ def test_paths_are_what_they_say(kernel):
     _, kw, plan, _ = PATHS["bitflip_audited"]
     res, st = _serve(kernel, img[:3], True, plan, **kw)
     assert st["audit_checked"] == 3 and st["audit_corrected"] == 1
+    _, kw, plan, _ = PATHS["retried"]
+    res, st = _serve(kernel, img[:1], True, plan, **kw)
+    assert res[0].tier == "fused"
+    assert st["retries"] == 1 and st["recovered"] == 1
+    _, kw, plan, _ = PATHS["bisected"]
+    res, st = _serve(kernel, img[:3], True, plan, **kw)
+    assert {r.tier for r in res} == {"fused"}
+    assert st["bisections"] == 1 and st["recovered"] == 3
 
 
 def test_a_batch_never_mixes_host_and_device_images(kernel):

@@ -92,7 +92,7 @@ def test_chaos_replay_log_is_reproducible():
 
 def _replay(stream, injector=None, **kw):
     sched = MVEScheduler(CFG, promote_after=2, injector=injector,
-                         audit_rate=1.0, audit_method="cross", **kw)
+                         audit_rate=1.0, **kw)
     tickets = [sched.submit(r.program, r.memory) for _, r in stream]
     t0 = time.perf_counter()
     sched.drain()
@@ -294,7 +294,7 @@ def test_bitflip_is_caught_and_corrected_by_audit():
                                 word=5, bit=12)])
     inj = FaultInjector(plan)
     with MVEScheduler(CFG, promote_after=None, injector=inj,
-                      audit_rate=1.0, audit_method="cross") as s:
+                      audit_rate=1.0) as s:
         ts = [s.submit(r.program, r.memory) for r in reqs]
         s.drain()
         assert s.stats.audit_corrected == 1
