@@ -59,6 +59,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import functools
 import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
@@ -72,7 +73,8 @@ from .isa import Instr, Op
 from .cost import TraceEvent
 from .machine import (JNP_DTYPE, ControlState, MVEConfig, apply_config,
                       cbs_from_lane_mask, flatten_indices, lane_dim_mask,
-                      store_layout, stream_shape, touched_lines)
+                      shift_store_layout, store_shape, stream_shape,
+                      touched_lines)
 from .tracing import LOWER, span, to_device, to_host
 from .vm import AotJit, VMProgram, VMUnsupported, fire_fault_hook
 from .vm import cache_info as _vm_cache_info
@@ -195,29 +197,35 @@ class CompiledProgram:
 
     # -- compilation -------------------------------------------------------
     def _compile_walk(self) -> None:
-        """Symbolically execute config ops; resolve every access statically."""
+        """Symbolically execute config ops; resolve every access statically.
+
+        The per-lane work (masks, offsets, line counts, store layouts)
+        depends on the control state, not on the instruction: it comes
+        from the walk memo (:func:`_walk_memo`), and an instruction's base
+        only shifts it."""
         cfg = self.cfg
+        lanes = cfg.lanes
         ctrl = ControlState()
+        no_cbs = _no_cbs(cfg.num_cbs)
         n_masked = 0
         for instr in self.program:
             op = instr.op
             if op in isa.CONFIG_OPS:
                 apply_config(ctrl, instr)
                 self.steps.append(_Step(instr, event=TraceEvent(
-                    op=op, dtype=None, elements=0,
-                    cb_mask=np.zeros(cfg.num_cbs, dtype=bool))))
+                    op=op, dtype=None, elements=0, cb_mask=no_cbs)))
                 continue
             if op is Op.SCALAR:
                 self.steps.append(_Step(instr, event=TraceEvent(
-                    op=op, dtype=None, elements=0,
-                    cb_mask=np.zeros(cfg.num_cbs, dtype=bool),
+                    op=op, dtype=None, elements=0, cb_mask=no_cbs,
                     scalar_count=instr.scalar_count)))
                 continue
 
             dims = ctrl.active_dims()
-            lane_mask = lane_dim_mask(dims, ctrl.dim_mask, cfg.lanes)
-            cbm = cbs_from_lane_mask(lane_mask, cfg)
-            elements = int(lane_mask.sum())
+            dim_mask = ctrl.dim_mask.tobytes()
+            lane_mask, cbm, elements = _walk_memo(
+                ("lanes", lanes, cfg.num_cbs, dims, dim_mask),
+                lambda: _lane_entry(dims, ctrl.dim_mask, cfg))
             step = _Step(instr, lane_mask=lane_mask, cb_mask=cbm,
                          mask_slot=n_masked)
             n_masked += 1
@@ -226,32 +234,28 @@ class CompiledProgram:
                 store = op in (Op.SST, Op.RST)
                 random = op in (Op.RLD, Op.RST)
                 strides = ctrl.resolve_strides(instr.modes or (), store)
-                run, segs, uniq = stream_shape(dims, strides, cfg.lanes)
-                coords = flatten_indices(dims, cfg.lanes)
+                shape = (lanes, dims, strides)
+                run, segs, uniq, offsets, top_idx = _walk_memo(
+                    ("offsets", random) + shape,
+                    lambda: _offsets_entry(dims, strides, lanes, random))
                 if random:
-                    top_len = dims[-1]
-                    offsets = np.zeros(cfg.lanes, dtype=np.int64)
-                    for d in range(len(dims) - 1):
-                        offsets += np.where(coords[:, d] >= 0,
-                                            coords[:, d], 0) * strides[d]
                     step.ptr_base = instr.base
-                    step.top_len = top_len
-                    step.top_idx = np.clip(coords[:, len(dims) - 1],
-                                           0, top_len - 1)
+                    step.top_len = dims[-1]
+                    step.top_idx = top_idx
                     step.offsets = offsets
                     step.rand_slot = self.n_random
                     self.n_random += 1
                     lines = 0          # filled from run-time addresses
                 else:
-                    addr = np.full(cfg.lanes, instr.base, dtype=np.int64)
-                    for d in range(len(dims)):
-                        addr += np.where(coords[:, d] >= 0,
-                                         coords[:, d], 0) * strides[d]
+                    addr = offsets + instr.base
                     step.addr = addr
                     if store:
-                        step.store_layout = store_layout(addr, lane_mask)
-                    lines = touched_lines(addr, lane_mask,
-                                          instr.dtype.nbytes)
+                        step.store_layout = shift_store_layout(_walk_memo(
+                            ("store", dim_mask) + shape,
+                            lambda: store_shape(offsets, lane_mask)),
+                            instr.base, addr, lane_mask)
+                    lines = _lines(instr, offsets, addr, lane_mask,
+                                   ("lines", dim_mask) + shape)
                 step.event = TraceEvent(op, instr.dtype, elements, cbm,
                                         segments=segs, contiguous_run=run,
                                         unique_elements=uniq, lines=lines)
@@ -773,6 +777,92 @@ _TAG_COUNTS: Dict[str, List[int]] = {}
 DEFAULT_MODE = "vm"
 
 
+# ---------------------------------------------------------------------------
+# Walk memo: the compile walk's per-lane work (lane and CB masks, address
+# offsets, line counts, store layouts) depends only on the control state it
+# reads, which repeats across programs — a stream of fresh programs differs
+# in bases and counts, not in shapes.  Each entry is keyed on everything its
+# computation reads, and an instruction's base only shifts the result, so a
+# hit is exact by construction (docs/ENGINE.md "Walk memo").  Entries are
+# read-only (steps share them) and bounded by the bytes they hold.
+# ---------------------------------------------------------------------------
+
+_WALK: "OrderedDict[tuple, Tuple[object, int]]" = OrderedDict()
+_WALK_CAPACITY_BYTES = 64 << 20    # ~1000 8192-lane int64 rows
+_WALK_ENTRY_BYTES = 256            # charged per entry besides its arrays
+_WALK_BYTES = _WALK_HITS = _WALK_MISSES = 0
+
+
+def _walk_memo(key: tuple, compute):
+    """The memo's entry for ``key``, computed on a miss.  The lock covers
+    the bookkeeping only, never the numpy work: a racing duplicate
+    computes the same value and the first insertion wins."""
+    global _WALK_BYTES, _WALK_HITS, _WALK_MISSES
+    with _CACHE_LOCK:
+        got = _WALK.get(key)
+        if got is not None:
+            _WALK_HITS += 1
+            _WALK.move_to_end(key)
+            return got[0]
+        _WALK_MISSES += 1
+    value = compute()
+    size = _WALK_ENTRY_BYTES
+    for v in value if isinstance(value, tuple) else ():
+        if isinstance(v, np.ndarray):
+            v.setflags(write=False)
+            size += v.nbytes
+    entry = (value, size)
+    with _CACHE_LOCK:
+        got = _WALK.setdefault(key, entry)
+        if got is entry:
+            _WALK_BYTES += size
+            while _WALK_BYTES > _WALK_CAPACITY_BYTES:
+                _WALK_BYTES -= _WALK.popitem(last=False)[1][1]
+    return got[0]
+
+
+@functools.lru_cache(maxsize=16)
+def _no_cbs(num_cbs: int) -> np.ndarray:
+    """The CB mask of config and scalar ops: no CB takes part."""
+    row = np.zeros(num_cbs, dtype=bool)
+    row.setflags(write=False)
+    return row
+
+
+def _lane_entry(dims, dim_mask, cfg: MVEConfig) -> tuple:
+    """(lane mask, CB mask, element count) of a vector instruction."""
+    lane_mask = lane_dim_mask(dims, dim_mask, cfg.lanes)
+    return (lane_mask, cbs_from_lane_mask(lane_mask, cfg),
+            int(lane_mask.sum()))
+
+
+def _offsets_entry(dims, strides, lanes: int, random: bool) -> tuple:
+    """(run, segments, unique elements, offsets, top_idx) of a memory
+    access: ``stream_shape``'s triple and the base-free element offsets
+    (a random access leaves its top dimension to the pointer array,
+    ``top_idx``)."""
+    coords = flatten_indices(dims, lanes)
+    inner = len(dims) - 1 if random else len(dims)
+    offsets = np.zeros(lanes, dtype=np.int64)
+    for d in range(inner):
+        offsets += np.where(coords[:, d] >= 0, coords[:, d], 0) * strides[d]
+    top_idx = (np.clip(coords[:, inner], 0, dims[-1] - 1) if random
+               else None)
+    return stream_shape(dims, strides, lanes) + (offsets, top_idx)
+
+
+def _lines(instr: Instr, offsets, addr, lane_mask, key: tuple) -> int:
+    """``touched_lines`` of a static access.  Where an element's size
+    divides the line, a base moved by whole lines moves every line index
+    by one, so the count depends on the base only modulo a line."""
+    nbytes = instr.dtype.nbytes
+    if 64 % nbytes:
+        return touched_lines(addr, lane_mask, nbytes)
+    rem = instr.base % (64 // nbytes)
+    return _walk_memo(key + (nbytes, rem), lambda: touched_lines(
+        offsets + rem, lane_mask, nbytes))
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineCacheInfo:
     """Snapshot of the compile caches (see :func:`cache_info`)."""
@@ -786,6 +876,8 @@ class EngineCacheInfo:
     vm_hits: int               # VM executor-cache hits
     vm_xla_compiles: int       # distinct VM XLA compilations (incl. batch)
     instr_hashes: int          # Instr hashes computed (first uses only)
+    walk_hits: int             # compile-walk memo lookups served
+    walk_misses: int           # compile-walk memo lookups computed
     # cache_tag -> {"hits": n, "misses": n} for target-tagged compiles
     # (docs/TARGETS.md); untagged compiles count only in the totals above.
     per_target: Dict[str, Dict[str, int]] = dataclasses.field(
@@ -800,7 +892,9 @@ def cache_info() -> EngineCacheInfo:
     :mod:`repro.targets` target that has compiled anything.
     ``instr_hashes`` counts the instruction hashes computed since the
     process started: each :class:`~repro.core.isa.Instr` computes its
-    hash once, so a lookup of a program seen before adds nothing."""
+    hash once, so a lookup of a program seen before adds nothing.
+    ``walk_hits`` / ``walk_misses`` count the compile walk's memo lookups
+    (per-lane work reused / computed) since the last :func:`clear_cache`."""
     v = _vm_cache_info()
     with _CACHE_LOCK:
         per_target = {tag: {"hits": c[0], "misses": c[1]}
@@ -810,7 +904,8 @@ def cache_info() -> EngineCacheInfo:
         program_evictions=_EVICTIONS, program_size=len(_CACHE),
         vm_fallbacks=_VM_FALLBACKS, vm_signatures=v.signatures,
         vm_hits=v.hits, vm_xla_compiles=v.xla_compiles,
-        instr_hashes=isa.instr_hashes(), per_target=per_target)
+        instr_hashes=isa.instr_hashes(), walk_hits=_WALK_HITS,
+        walk_misses=_WALK_MISSES, per_target=per_target)
 
 
 def _attach_kernel(cp: CompiledProgram, kernel) -> CompiledProgram:
@@ -937,12 +1032,16 @@ def compile_program(program: isa.Program,
 
 
 def clear_cache() -> None:
-    """Drop all cached compilations and reset the LRU counters (tests /
-    memory pressure).  VM executables persist — clear them separately via
-    :func:`repro.core.vm.clear_executors` when measuring cold starts."""
+    """Drop all cached compilations and the walk memo, and reset their
+    counters (tests / memory pressure).  VM executables persist — clear
+    them separately via :func:`repro.core.vm.clear_executors` when
+    measuring cold starts."""
     global _HITS, _MISSES, _EVICTIONS, _VM_FALLBACKS
+    global _WALK_BYTES, _WALK_HITS, _WALK_MISSES
     with _CACHE_LOCK:
         _CACHE.clear()
         _TAG_COUNTS.clear()
         _HITS = _MISSES = _EVICTIONS = 0
         _VM_FALLBACKS = 0
+        _WALK.clear()
+        _WALK_BYTES = _WALK_HITS = _WALK_MISSES = 0

@@ -340,14 +340,19 @@ def store_layout(addr: np.ndarray, mask: np.ndarray):
       last-lane-wins scatter order) and ``perm`` reorders the source lanes
       to match.
     """
-    lanes = addr.shape[0]
     if not mask.any():
         return ("none",)
-    lane = np.arange(lanes, dtype=np.int64)
-    delta = addr[mask] - lane[mask]
+    delta = addr[mask] - np.flatnonzero(mask)
     base = int(delta[0])
     if base >= 0 and (delta == base).all():
         return ("contig", base)
+    return ("scatter",) + _scatter_layout(addr, mask)[:2]
+
+
+def _scatter_layout(addr: np.ndarray, mask: np.ndarray):
+    """``store_layout``'s scatter rows ``(idx, perm)``, with the number of
+    winning lanes and their highest address."""
+    lane = np.arange(addr.shape[0], dtype=np.int64)
     # Keep, per distinct address, only the highest active lane (last wins).
     act = np.flatnonzero(mask)
     order_a = np.argsort(addr[act], kind="stable")
@@ -358,7 +363,44 @@ def store_layout(addr: np.ndarray, mask: np.ndarray):
     key = OOB_BASE + lane
     key[winners] = addr[winners]
     perm = np.argsort(key, kind="stable")
-    return ("scatter", key[perm].astype(np.int64), perm.astype(np.int32))
+    return (key[perm].astype(np.int64), perm.astype(np.int32),
+            len(winners), int(sorted_a[-1]))
+
+
+def store_shape(offsets: np.ndarray, mask: np.ndarray) -> tuple:
+    """The base-free part of :func:`store_layout` for the addresses
+    ``base + offsets``, computed once for every base.
+
+    Which lanes win is shift-invariant, and so is the sort order of the
+    scatter keys while every winning address stays below ``OOB_BASE``.
+    :func:`shift_store_layout` adds the base back.
+    """
+    if not mask.any():
+        return ("none",)
+    delta = offsets[mask] - np.flatnonzero(mask)
+    if (delta == delta[0]).all():
+        return ("contig", int(delta[0]))
+    return ("scatter",) + _scatter_layout(offsets, mask)
+
+
+def shift_store_layout(shape: tuple, base: int, addr: np.ndarray,
+                       mask: np.ndarray):
+    """``store_layout(addr, mask)`` for ``addr = base + offsets``, from
+    ``store_shape(offsets, mask)``; the few cases whose shift is not exact
+    (a negative contiguous base, a winner at or above ``OOB_BASE``) are
+    classified from ``addr`` itself."""
+    kind = shape[0]
+    if kind == "none":
+        return shape
+    if kind == "contig" and shape[1] + base >= 0:
+        return ("contig", shape[1] + base)
+    if kind == "scatter":
+        _, idx, perm, winners, top = shape
+        if top < OOB_BASE and top + base < OOB_BASE:
+            idx = idx.copy()
+            idx[:winners] += base
+            return ("scatter", idx, perm)
+    return store_layout(addr, mask)
 
 
 def cbs_touched(dims: Tuple[int, ...], dim_mask: np.ndarray,
