@@ -67,14 +67,15 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import isa
 from .isa import Instr, Op
 from .cost import TraceEvent
 from .machine import (JNP_DTYPE, ControlState, MVEConfig, apply_config,
                       cbs_from_lane_mask, flatten_indices, lane_dim_mask,
-                      shift_store_layout, store_shape, stream_shape,
-                      touched_lines)
+                      load_shape, shift_store_layout, store_shape,
+                      stream_shape, touched_lines)
 from .tracing import LOWER, span, to_device, to_host
 from .vm import AotJit, VMProgram, VMUnsupported, fire_fault_hook
 from .vm import cache_info as _vm_cache_info
@@ -123,6 +124,7 @@ class _Step:
     mask_slot: Optional[int] = None          # row in the runtime mask stack
     addr: Optional[np.ndarray] = None        # static element addresses
     store_layout: Optional[tuple] = None     # machine.store_layout result
+    load_layout: Optional[tuple] = None      # machine.load_shape, placed
     # random-base (Eq. 1) accesses: pointer slice + static inner offsets
     ptr_base: Optional[int] = None
     top_len: Optional[int] = None
@@ -185,6 +187,7 @@ class CompiledProgram:
                                         _NAMES.get(self.program))
         self._jit = AotJit(self._fused_fn, donate_argnums=(0,))
         self._batch_jit = None
+        self._loads_counted = False    # _count_loads: first trace only
         self._vm: Optional[VMProgram] = None
         self.mode = mode
         if mode == "vm":
@@ -254,6 +257,13 @@ class CompiledProgram:
                             ("store", dim_mask) + shape,
                             lambda: store_shape(offsets, lane_mask)),
                             instr.base, addr, lane_mask)
+                    else:
+                        layout = _walk_memo(
+                            ("load", dim_mask) + shape,
+                            lambda: load_shape(dims, strides, lane_mask,
+                                               lanes))
+                        step.load_layout = (layout[:1] + (instr.base,)
+                                            + layout[1:])
                     lines = _lines(instr, offsets, addr, lane_mask,
                                    ("lines", dim_mask) + shape)
                 step.event = TraceEvent(op, instr.dtype, elements, cbm,
@@ -281,6 +291,7 @@ class CompiledProgram:
         tag = jnp.ones(cfg.lanes, dtype=bool)
         rand_addrs: List[jnp.ndarray] = [None] * self.n_random
         hi = memory.shape[0] - 1
+        loads = [0, 0]                # static loads gathered, sliced
 
         for step in self.steps:
             instr = step.instr
@@ -291,19 +302,24 @@ class CompiledProgram:
             dt = JNP_DTYPE.get(instr.dtype, jnp.float32)
             jmask = masks[step.mask_slot]
             if step.mask_slot and step.mask_slot % FUSE_SPAN == 0:
-                memory, regs, tag = jax.lax.optimization_barrier(
-                    (memory, regs, tag))
+                memory, regs, tag = _batch_major(jax.lax.optimization_barrier(
+                    (memory, regs, tag)))
 
             def old(vd, dt=dt):
                 v = regs.get(vd)
                 return (zeros if v is None else v).astype(dt)
 
             if op in (Op.SLD, Op.RLD):
-                addr = self._address_vector(step, memory)
-                if step.rand_slot is not None:
-                    rand_addrs[step.rand_slot] = addr
-                gathered = memory[jnp.clip(addr, 0, hi)].astype(dt)
-                regs[instr.vd] = jnp.where(jmask, gathered, old(instr.vd))
+                value = _read_box(memory, step.load_layout, cfg.lanes)
+                if op is Op.SLD:
+                    loads[value is not None] += 1
+                if value is None:
+                    addr = self._address_vector(step, memory)
+                    if step.rand_slot is not None:
+                        rand_addrs[step.rand_slot] = addr
+                    value = memory[jnp.clip(addr, 0, hi)]
+                regs[instr.vd] = jnp.where(jmask, value.astype(dt),
+                                           old(instr.vd))
                 continue
             if op in (Op.SST, Op.RST):
                 src = old(instr.vs1).astype(memory.dtype)
@@ -395,6 +411,8 @@ class CompiledProgram:
             else:
                 raise NotImplementedError(f"op {op}")
 
+        _count_loads(self, *loads)
+        memory, regs, tag = _batch_major((memory, regs, tag))
         return memory, regs, tag, rand_addrs
 
     @staticmethod
@@ -705,6 +723,57 @@ def take_row(tree, row):
     return jax.tree.map(lambda a: a[row], tree)
 
 
+def _read_box(memory, layout, lanes: int):
+    """The words a static load reads, in lane order, by slices,
+    reshapes and broadcasts (``machine.load_shape``); None where the load
+    is a gather: its layout says so, or its window runs off ``memory``.
+
+    A vmapped gather makes XLA:TPU lay the memory out batch-minor, and
+    every barrier then relays out the whole image; slices keep the
+    layout the memory has."""
+    if layout is None or layout[0] == "gather":
+        return None
+    if layout[0] == "none":
+        return jnp.zeros(lanes, memory.dtype)
+    _, lo, span, levels, perm, shape, bshape, used = layout
+    size = memory.shape[0]
+    if lo < 0 or lo + span > size:
+        return None
+    # Each level but the last splits a run of n * t words into n rows of
+    # t, of which the next level needs n' * t' (or the rows' end); read
+    # what memory holds of the first run and pad only what it lacks.
+    need = levels[0][0] * levels[0][1] if len(levels) > 1 else span
+    v = memory[lo:lo + min(need, size - lo)]
+    for (n, t), (n2, t2) in zip(levels, levels[1:]):
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, n * t - v.shape[-1])])
+        v = v.reshape(v.shape[:-1] + (n, t))[..., :min(t, n2 * t2)]
+    v = (jax.lax.slice_in_dim(v, 0, v.shape[-1], levels[-1][1], axis=-1)
+         if levels else v[0])
+    v = jnp.broadcast_to(v.transpose(perm).reshape(shape), bshape)
+    return jnp.pad(v.reshape(-1)[:used], (0, lanes - used))
+
+
+def _batch_major(tree):
+    """``tree`` (1-D arrays) pinned to the default layout, which under
+    ``vmap`` puts the batch major.  XLA:TPU otherwise lays a batch out
+    batch-minor where some op prefers it (a scatter, a reshape to a small
+    minor extent), pads 16 images to 128 lanes, and carries that layout
+    through the registers and the whole memory image; pinned at the
+    barriers and the outputs, only the operands of such ops relay out."""
+    return with_layout_constraint(
+        tree, jax.tree.map(lambda _: Layout((0,)), tree))
+
+
+def _count_loads(cp: "CompiledProgram", gathered: int, sliced: int) -> None:
+    """Count a program's static loads by lowering, on its first trace."""
+    global _LOADS_GATHERED, _LOADS_SLICED
+    with _CACHE_LOCK:
+        if not cp._loads_counted:
+            cp._loads_counted = True
+            _LOADS_GATHERED += gathered
+            _LOADS_SLICED += sliced
+
+
 #: A fused program gets an optimization barrier on its memory, registers
 #: and Tag every ``FUSE_SPAN`` vector instructions.  Every write-back
 #: selects on the register's old value, so each value feeds two
@@ -764,6 +833,7 @@ _CACHE_CAPACITY = 256
 _CACHE_LOCK = threading.RLock()   # submit() may compile from many threads
 _HITS = _MISSES = _EVICTIONS = 0
 _VM_FALLBACKS = 0
+_LOADS_SLICED = _LOADS_GATHERED = 0
 # Per-target LRU counters: cache_tag -> [hits, misses].  Tagged compiles
 # (one tag per :mod:`repro.targets` target) get their own key space, so
 # an RVV or Neon compilation of a program never aliases — or evicts in
@@ -878,6 +948,8 @@ class EngineCacheInfo:
     instr_hashes: int          # Instr hashes computed (first uses only)
     walk_hits: int             # compile-walk memo lookups served
     walk_misses: int           # compile-walk memo lookups computed
+    loads_sliced: int          # static loads lowered as slices (fused)
+    loads_gathered: int        # static loads lowered as gathers (fused)
     # cache_tag -> {"hits": n, "misses": n} for target-tagged compiles
     # (docs/TARGETS.md); untagged compiles count only in the totals above.
     per_target: Dict[str, Dict[str, int]] = dataclasses.field(
@@ -894,7 +966,10 @@ def cache_info() -> EngineCacheInfo:
     process started: each :class:`~repro.core.isa.Instr` computes its
     hash once, so a lookup of a program seen before adds nothing.
     ``walk_hits`` / ``walk_misses`` count the compile walk's memo lookups
-    (per-lane work reused / computed) since the last :func:`clear_cache`."""
+    (per-lane work reused / computed) since the last :func:`clear_cache`.
+    ``loads_sliced`` / ``loads_gathered`` count the static loads of fused
+    programs by how they were lowered (``machine.load_shape``), once per
+    compiled program, on its first trace."""
     v = _vm_cache_info()
     with _CACHE_LOCK:
         per_target = {tag: {"hits": c[0], "misses": c[1]}
@@ -905,7 +980,8 @@ def cache_info() -> EngineCacheInfo:
         vm_fallbacks=_VM_FALLBACKS, vm_signatures=v.signatures,
         vm_hits=v.hits, vm_xla_compiles=v.xla_compiles,
         instr_hashes=isa.instr_hashes(), walk_hits=_WALK_HITS,
-        walk_misses=_WALK_MISSES, per_target=per_target)
+        walk_misses=_WALK_MISSES, loads_sliced=_LOADS_SLICED,
+        loads_gathered=_LOADS_GATHERED, per_target=per_target)
 
 
 def _attach_kernel(cp: CompiledProgram, kernel) -> CompiledProgram:
@@ -1038,6 +1114,7 @@ def clear_cache() -> None:
     measuring cold starts."""
     global _HITS, _MISSES, _EVICTIONS, _VM_FALLBACKS
     global _WALK_BYTES, _WALK_HITS, _WALK_MISSES
+    global _LOADS_SLICED, _LOADS_GATHERED
     with _CACHE_LOCK:
         _CACHE.clear()
         _TAG_COUNTS.clear()
@@ -1045,3 +1122,4 @@ def clear_cache() -> None:
         _VM_FALLBACKS = 0
         _WALK.clear()
         _WALK_BYTES = _WALK_HITS = _WALK_MISSES = 0
+        _LOADS_SLICED = _LOADS_GATHERED = 0

@@ -403,6 +403,60 @@ def shift_store_layout(shape: tuple, base: int, addr: np.ndarray,
     return store_layout(addr, mask)
 
 
+def load_shape(dims: Tuple[int, ...], strides: Tuple[int, ...],
+               mask: np.ndarray, lanes: int) -> tuple:
+    """How a static load of ``base + offsets`` reads memory, computed
+    once for every base (the base only places the window).
+
+    * ``("none",)`` — no active lane: nothing is read.
+    * ``("box", span, levels, perm, shape, bshape, used)`` — the lanes up
+      to the last active one read a box: broadcast (stride-0) dimensions
+      over nested strided ones, whose positive strides, sorted, each
+      clear the span of those below.  The words lie in the window
+      ``[base, base + span)``.  ``levels`` holds ``(extent, stride)``
+      per nested dimension, largest stride first: the window reshaped
+      level by level and cut to what the level below reads.  ``perm``
+      transposes the levels to lane order (dimension 0 fastest),
+      ``shape`` (1 for a broadcast) and ``bshape`` broadcast them, and
+      the first ``used`` words of the flattened box are lanes ``0..``.
+    * ``("gather",)`` — anything else (negative or overlapping strides).
+
+    The highest dimension is cut after the last active lane's index in
+    it, so dimension-masked rows past it need not lie in memory.
+    """
+    if not mask.any():
+        return ("none",)
+    last = int(np.flatnonzero(mask)[-1])
+    top = int(flatten_indices(dims, lanes)[last, -1]) + 1
+    dims = tuple(dims[:-1]) + (top,)
+    used = min(lanes, int(np.prod(dims)))
+    merged: List[List[int]] = []          # [extent, stride], lane order
+    for n, s in zip(dims, strides):
+        if n == 1:
+            continue
+        if merged and s == merged[-1][0] * merged[-1][1]:
+            merged[-1][0] *= n            # dense continuation, or broadcast
+        else:
+            merged.append([n, s])
+    if any(s < 0 for _, s in merged):
+        return ("gather",)
+    order = sorted((i for i, (_, s) in enumerate(merged) if s),
+                   key=lambda i: merged[i][1])
+    levels, span = [], 1
+    for i in order:
+        n, s = merged[i]
+        if s < span:                      # overlaps the levels below
+            return ("gather",)
+        levels.append((n, s))
+        span += (n - 1) * s
+    order.reverse()
+    perm = tuple(order.index(i) for i in reversed(range(len(merged)))
+                 if merged[i][1])
+    shape = tuple(n if s else 1 for n, s in reversed(merged))
+    bshape = tuple(n for n, _ in reversed(merged))
+    return ("box", span, tuple(levels[::-1]), perm, shape, bshape, used)
+
+
 def cbs_touched(dims: Tuple[int, ...], dim_mask: np.ndarray,
                 cfg: MVEConfig) -> np.ndarray:
     """Which control blocks have at least one active lane (mask bit-vector

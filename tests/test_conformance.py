@@ -38,6 +38,7 @@ touch functional semantics).
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from _load_cases import LOAD_CASES, load_memories
 
 from repro import opt, targets
 from repro.core import MVEConfig, MVEInterpreter, compile_program, isa, rvv
@@ -368,6 +369,15 @@ def test_cb_masked_store_all_executors():
             isa.vsst(DType.F, 0, _OUT + 256, 3, 2),    # masked scatter
             isa.vsetmask(2)]
     _check_all_executors(prog, [mem])
+
+
+@pytest.mark.parametrize("case", sorted(LOAD_CASES))
+def test_static_load_classes_all_executors(case):
+    """One static load of each address pattern the fused tier tells
+    apart (a box read by slices and broadcasts, or the clipped gather),
+    over three images, through every executor."""
+    program, _ = LOAD_CASES[case]
+    _check_all_executors(program, load_memories(seed=len(case), count=3))
 
 
 def test_random_base_gather_batched_pointer_tables():

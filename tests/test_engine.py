@@ -13,6 +13,7 @@ import threading
 
 import numpy as np
 import pytest
+from _load_cases import LOAD_CASES, load_memories
 
 from repro.core import MVEConfig, MVEInterpreter, compile_program, engine, isa
 from repro.core.engine import CompiledProgram, cache_info, clear_cache
@@ -308,3 +309,48 @@ def test_walk_memo_under_concurrent_walks():
     for t, snaps in enumerate(got):
         assert snaps == want[t % 2:] + want[:t % 2]
     clear_cache()
+
+
+# -- static loads: slices and broadcasts, or the gather (machine.load_shape) --
+
+@pytest.mark.parametrize("batch", [None, 16])
+@pytest.mark.parametrize("case", sorted(LOAD_CASES))
+def test_static_load_lowering_matches_interpreter(case, batch):
+    """Each address pattern, alone and in a batch of 16 images, gives
+    the interpreter's memory, registers and Tag bit for bit, lowered
+    the way ``cache_info()`` counts it."""
+    program, want = LOAD_CASES[case]
+    mems = load_memories(seed=len(case), count=batch or 1)
+    clear_cache()
+    if batch is None:
+        _assert_equivalent(program, mems[0])
+    else:
+        cp = compile_program(program, CFG, mode="fused")
+        got_mem, got_regs, got_tag = cp.run_batch(np.stack(mems))
+        for i, mem in enumerate(mems):
+            mem_i, st_i = ORACLE.run_stepwise(program, mem)
+            np.testing.assert_array_equal(np.asarray(mem_i),
+                                          np.asarray(got_mem[i]))
+            for r in st_i.regs:
+                np.testing.assert_array_equal(np.asarray(st_i.regs[r]),
+                                              np.asarray(got_regs[r][i]))
+            np.testing.assert_array_equal(np.asarray(st_i.tag),
+                                          np.asarray(got_tag[i]))
+    info = cache_info()
+    assert (info.loads_sliced, info.loads_gathered) == want
+
+
+def test_load_counters_count_each_program_once():
+    """A program's loads count once, however many executables trace
+    it, and ``clear_cache()`` zeroes the counters."""
+    program, _ = LOAD_CASES["box_3d"]
+    clear_cache()
+    cp = compile_program(program, CFG, mode="fused")
+    mem = load_memories(seed=0, count=2)
+    cp.run(mem[0])
+    cp.run_batch(np.stack(mem))
+    assert (cache_info().loads_sliced, cache_info().loads_gathered) == (1, 0)
+    compile_program(program, CFG, mode="vm").run(mem[0])
+    assert cache_info().loads_sliced == 1        # the VM lowers nothing
+    clear_cache()
+    assert (cache_info().loads_sliced, cache_info().loads_gathered) == (0, 0)

@@ -27,10 +27,11 @@ import hashlib
 import jax
 import numpy as np
 import pytest
+from _load_cases import lowered_loads
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import MVEConfig
-from repro.core.engine import compile_program
+from repro.core.engine import cache_info, clear_cache, compile_program
 from repro.core.interp import MVEInterpreter
 from repro.nn import lfm2, lfm2_ref, qwen2
 from repro.runtime.scheduler import MVEScheduler
@@ -360,3 +361,25 @@ def test_qwen2_runner_stages_and_copies_unchanged():
 #: sha256 of :func:`_qwen2_runner_record`'s log.
 QWEN2_RUNNER_LOG = ("7e1463e685fbfdc6467cefc722d093dc"
                     "6355d5dfc426b9bd1b6a92ba55009889")
+
+
+@pytest.mark.parametrize("kind", lfm2.KINDS)
+def test_tile_loads_lower_as_slices(kind):
+    """Every static load of every tile kind is a box the fused tier reads
+    by slices and broadcasts, with no gather (``engine.cache_info()``);
+    ``clear_cache()`` zeroes the counts."""
+    t = lfm2.tiles(SH)[kind]
+    sliced, gathered, loads = lowered_loads(t.program, t.zeros.size)
+    assert (sliced, gathered) == (loads, 0) and loads > 0
+    clear_cache()
+    assert (cache_info().loads_sliced, cache_info().loads_gathered) == (0, 0)
+
+
+def test_published_gemv_loads_lower_as_slices():
+    """The published ``gemv_d`` tile's 80 static loads: 47 runs of ``w``
+    and partial sums, 33 broadcasts of ``x`` and its scale."""
+    full = lfm2.Shapes.of(get_config("lfm2-8b-a1b"), split=1024,
+                          max_splits=8, vocab=8192)
+    t = lfm2.tiles(full)["gemv_d"]
+    assert lowered_loads(t.program, t.zeros.size) == (80, 0, 80)
+    clear_cache()

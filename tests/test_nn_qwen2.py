@@ -25,10 +25,11 @@ import threading
 import jax
 import numpy as np
 import pytest
+from _load_cases import lowered_loads
 
 from repro.configs import get_config
 from repro.core import MVEConfig
-from repro.core.engine import compile_program
+from repro.core.engine import cache_info, clear_cache, compile_program
 from repro.core.interp import MVEInterpreter
 from repro.core.isa import DType
 from repro.frontend import SEQ, KernelBuilder
@@ -299,3 +300,24 @@ def test_round_even_ties():
     xs = [0.5, 1.5, 2.5, -1.5, -2.5, 126.6, -127.4, 3.49999]
     got = _unary(lambda b, v: ops.round_even(b, v), xs)
     np.testing.assert_array_equal(got, np.round(np.float32(xs)))
+
+
+@pytest.mark.parametrize("kind", qwen2.KINDS)
+def test_tile_loads_lower_as_slices(kind):
+    """Every static load of every tile kind is a box the fused tier reads
+    by slices and broadcasts, with no gather (``engine.cache_info()``);
+    ``clear_cache()`` zeroes the counts."""
+    t = qwen2.tiles(SH)[kind]
+    sliced, gathered, loads = lowered_loads(t.program, t.zeros.size)
+    assert (sliced, gathered) == (loads, 0) and loads > 0
+    clear_cache()
+    assert (cache_info().loads_sliced, cache_info().loads_gathered) == (0, 0)
+
+
+def test_published_gemv_loads_lower_as_slices():
+    """The published ``gemv_d`` tile's 44 static loads, all slices."""
+    full = qwen2.Shapes.of(get_config("qwen2-0.5b"), split=512,
+                           max_splits=16)
+    t = qwen2.tiles(full)["gemv_d"]
+    assert lowered_loads(t.program, t.zeros.size) == (44, 0, 44)
+    clear_cache()

@@ -10,6 +10,7 @@ loads the TPU library; where it cannot be described, the tests skip.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,15 +140,23 @@ def test_qwen2_pallas_prefill(one_chip):
 # Qwen2-0.5B decode tiles on the fused tier, at the published widths.
 # ---------------------------------------------------------------------------
 
+def _gathers(hlo: str) -> int:
+    return len(re.findall(r"= \S+ gather\(", hlo))
+
+
 @pytest.mark.parametrize("kind,batch", [("attn", 16), ("gemv_d", None)])
 def test_qwen2_decode_tile_fused(one_chip, kind, batch):
     """The longest decode tile batched 16 (its write-back chain broken by
-    ``engine.FUSE_SPAN`` barriers) and the GEMV tile, named by kind."""
+    ``engine.FUSE_SPAN`` barriers) and the GEMV tile, named by kind.  Its
+    gathers are those of the loads the fused tier could not slice
+    (``cache_info().loads_gathered``) and one per scattered store."""
     from repro.configs.qwen2_0_5b import CONFIG
+    from repro.core import engine
     from repro.core.engine import compile_program
     from repro.nn import qwen2
     sh = qwen2.Shapes.of(CONFIG, split=512, max_splits=16)
     tile = qwen2.tiles(sh)[kind]
+    engine.clear_cache()
     cp = compile_program(tile.program, mode="fused")
     masks, zeros = cp._fused_operands()
     fn, shape = cp._fused_fn, (tile.kernel.plan.size,)
@@ -158,8 +167,45 @@ def test_qwen2_decode_tile_fused(one_chip, kind, batch):
         _on(one_chip, shape, jnp.float32),
         _on(one_chip, masks.shape, masks.dtype),
         _on(one_chip, zeros.shape, zeros.dtype)).compile()
-    assert compiled.as_text().startswith(f"HloModule jit__execute.dec_{kind}")
+    hlo = compiled.as_text()
+    assert hlo.startswith(f"HloModule jit__execute.dec_{kind}")
     assert compiled.out_info[0].shape == shape
+    scattered = sum(s.store_layout is not None
+                    and s.store_layout[0] == "scatter" for s in cp.steps)
+    assert _gathers(hlo) == engine.cache_info().loads_gathered + scattered
+    if batch is not None:
+        # the batch's registers stay batch-major (pinned at the barriers)
+        rows = re.findall(r"f32\[%d,8192\]\{(\d),\d" % batch, hlo)
+        assert rows.count("1") > 10 * rows.count("0")
+
+
+@pytest.mark.parametrize("model", ["lfm2", "qwen2"])
+def test_gemv_tile_batch16_keeps_memory_layout(one_chip, model):
+    """The published ``gemv_d`` tile batched 16: every load is a slice or
+    a broadcast, so the executable holds no gather and no copy of the 16
+    memory images (a vmapped gather lays them out batch-minor, and each
+    barrier relaid out the whole batch)."""
+    from repro.core import engine
+    if model == "lfm2":
+        from repro.configs.lfm2_8b_a1b import CONFIG
+        from repro.nn import lfm2 as nn
+        sh = nn.Shapes.of(CONFIG, split=1024, max_splits=8, vocab=8192)
+    else:
+        from repro.configs.qwen2_0_5b import CONFIG
+        from repro.nn import qwen2 as nn
+        sh = nn.Shapes.of(CONFIG, split=512, max_splits=16)
+    tile = nn.tiles(sh)["gemv_d"]
+    cp = engine.compile_program(tile.program, mode="fused")
+    masks, zeros = cp._fused_operands()
+    shape = (16, tile.kernel.plan.size)
+    hlo = jax.jit(jax.vmap(cp._fused_fn, in_axes=(0, None, None)),
+                  donate_argnums=(0,)).lower(
+        _on(one_chip, shape, jnp.float32),
+        _on(one_chip, masks.shape, masks.dtype),
+        _on(one_chip, zeros.shape, zeros.dtype)).compile().as_text()
+    assert _gathers(hlo) == 0
+    images = r"f32\[%d,%d\]\{[^}]*\} copy\(" % shape
+    assert not re.search(images, hlo)
 
 
 @pytest.mark.parametrize("stage,kind,n", [("gate_up", "gemv_d", 76),
